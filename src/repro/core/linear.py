@@ -114,8 +114,8 @@ def prepare(params: dict[str, Any], cfg: SparsityConfig) -> dict[str, Any]:
     elif cfg.mode == "compressed":
         c = comp.compress(ws, dec, pack_values=rec.packed_weights)
         out["values"], out["indices"] = c.values, c.indices
-        # K is recoverable from the (pack-agnostic) indices shape; storing
-        # it as a pytree leaf would get traced to an abstract value under jit
+        # K is recoverable from the (pack-agnostic) indices shape [P, K/L,
+        # out]; storing it as a pytree leaf would get traced under jit
     else:
         raise ValueError(f"unknown mode {cfg.mode}")
     return out
@@ -191,7 +191,7 @@ def apply(params: dict[str, Any], x: jax.Array, cfg: SparsityConfig,
             slide.slided_matmul(x, ws, dec).astype(out_dtype), activation))
 
     if cfg.mode == "compressed":
-        k = params["indices"].shape[-1] * dec.source.l // dec.source.z
+        k = params["indices"].shape[-2] * dec.source.l  # [P, K/L, out]
         c = comp.CompressedSlided(
             params["values"], params["indices"], k,
             dec.source.z, dec.source.l, dec.hw.m, dec.hw.n,

@@ -48,7 +48,9 @@ def quantize_int8(x: jax.Array,
     a = _absmax(x) if absmax is None else absmax
     r = INT8_QMAX / a
     q = jnp.clip(jnp.round(x.astype(jnp.float32) * r), -INT8_QMAX, INT8_QMAX)
-    return Quantized(q.astype(jnp.int8), a / INT8_QMAX)
+    # times the reciprocal: under jit XLA turns a division by a constant
+    # into exactly this, so eager and jitted packing agree bit for bit
+    return Quantized(q.astype(jnp.int8), a * (1.0 / INT8_QMAX))
 
 
 def quantize_fp8(x: jax.Array,
@@ -86,7 +88,7 @@ def quantize_weight_int4_rowwise(w: jax.Array) -> Quantized:
     a = _absmax(w)
     r = INT4_QMAX / a
     q = jnp.clip(jnp.round(w.astype(jnp.float32) * r), -INT4_QMAX, INT4_QMAX)
-    return Quantized(q.astype(jnp.int8), a / INT4_QMAX)
+    return Quantized(q.astype(jnp.int8), a * (1.0 / INT4_QMAX))
 
 
 def matmul_dequant(qx: Quantized, qw: Quantized,

@@ -6,8 +6,8 @@ depend on the operand shapes, dtypes and backend.  This module picks them
 
 * ``lookup`` — two-level cache: an in-process dict, backed by an on-disk
   JSON file so tuned configurations survive across processes (serving
-  restarts, benchmark runs).  Set ``REPRO_AUTOTUNE_CACHE`` to relocate or
-  ``REPRO_AUTOTUNE_CACHE=''`` to disable persistence.
+  restarts, benchmark runs).  The file is used only when
+  ``REPRO_AUTOTUNE_CACHE`` names it; unset or empty, nothing persists.
 * ``autotune`` — times each candidate config with the caller-supplied
   runner (warmup + best-of-reps wall clock, like the benchmark harness)
   and records the winner.
@@ -71,11 +71,10 @@ _DISK_LOADED = False
 
 
 def cache_path() -> str | None:
-    path = os.environ.get("REPRO_AUTOTUNE_CACHE")
-    if path == "":
-        return None
-    return path or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "autotune.json")
+    """The on-disk cache file: ``REPRO_AUTOTUNE_CACHE`` when it names one,
+    else None — no persistence, so served tiles come from the code (the
+    kernels' heuristics) unless a cache file is explicitly given."""
+    return os.environ.get("REPRO_AUTOTUNE_CACHE") or None
 
 
 def _quarantine_cache(path: str, err: Exception) -> None:

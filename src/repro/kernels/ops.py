@@ -188,7 +188,7 @@ def compressed_matmul(x: jax.Array, c: CompressedSlided,
 
 def _compressed_tiles(x2, c, rec, tune, interpret, out_dtype, **call_kw):
     return autotune.tiles_for(
-        "compressed_matmul", rows=x2.shape[0], m=c.values.shape[0], k=c.k,
+        "compressed_matmul", rows=x2.shape[0], m=c.out_features, k=c.k,
         pattern=f"{c.z}:{c.l}", adt=rec.act or str(x2.dtype),
         wdt=rec.weight or str(c.values.dtype), interpret=interpret,
         tune=tune, operands=(x2, c.values),

@@ -10,13 +10,15 @@ attention traffic scales with pool *capacity*, not valid tokens.  This
 module consumes the page table inside the kernel instead:
 
 * Pallas path (TPU, or ``interpret=True`` on CPU): grid
-  ``(B, KVH, splits, pages_per_split)``.  The page table and per-row KV
+  ``(B, splits, pages_per_split)``.  The page table and per-row KV
   lengths arrive as scalar-prefetch operands, so each grid step's
   BlockSpec index_map reads the table and fetches exactly the physical
-  K/V (+ int8 scale) page it needs — the gathered copy never exists.
-  Each (batch, kv-head, split) cell runs online softmax — running max /
-  sum-exp / unnormalized accumulator in VMEM scratch — over its pages
-  and emits a partial ``(acc, m, l)``; the standard flash-decode
+  K/V (+ int8 scale) page it needs — all KV heads of it, a block whose
+  minor dims are the pool's own ``(KVH, hd)`` — and the gathered copy
+  never exists.  Each (batch, split) cell runs one online softmax per KV
+  head — running max / sum-exp / unnormalized accumulator in VMEM
+  scratch — over its pages and emits a partial ``(acc, m, l)``; the
+  standard flash-decode
   ``(max, sum)`` merge combines splits outside the kernel:
   ``m* = max_s m_s;  l* = sum_s l_s * exp(m_s - m*);
   out = sum_s acc_s * exp(m_s - m*) / l*``.
@@ -54,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune
+from .slide_matmul import vmem_limit
 
 NEG_INF = -1e30
 
@@ -138,16 +141,16 @@ def _flash_ref(q, pool, page_table, kv_len, window, block_pages):
 # ------------------------------------------------------------ Pallas path
 def _flash_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, *refs,
                   page_size, rep, pps, window, quant):
-    """One grid step: fold one physical page into the (m, l, acc) running
-    softmax of this (batch, kv-head, split) cell; flush the partial on
-    the split's last page."""
+    """One grid step: fold one physical page — all of its KV heads — into
+    the per-head (m, l, acc) running softmax of this (batch, split) cell;
+    flush the partials on the split's last page."""
     if quant:
         ks_ref, vs_ref, oacc_ref, m_ref, l_ref, acc_s, m_s, l_s = refs
     else:
         oacc_ref, m_ref, l_ref, acc_s, m_s, l_s = refs
     bb = pl.program_id(0)
-    s_idx = pl.program_id(2)
-    p_idx = pl.program_id(3)
+    s_idx = pl.program_id(1)
+    p_idx = pl.program_id(2)
 
     @pl.when(p_idx == 0)
     def _init():
@@ -155,14 +158,7 @@ def _flash_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, *refs,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    qv = q_ref[0, 0]                                   # [Lr, hd] (pre-scaled)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32)         # [P, hd]
-    vb = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quant:  # in-kernel dequant from the page's scale rows, oracle order
-        kb = kb * ks_ref[0, :, 0, :].astype(jnp.float32)
-        vb = vb * vs_ref[0, :, 0, :].astype(jnp.float32)
-
-    lr = qv.shape[0]
+    kvh, lr = q_ref.shape[1], q_ref.shape[2]
     pos = (s_idx * pps + p_idx) * page_size \
         + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (lr, 1), 0) // rep
@@ -171,32 +167,39 @@ def _flash_kernel(pt_ref, kl_ref, q_ref, k_ref, v_ref, *refs,
     if window is not None:
         ok &= pos >= row_len - window
 
-    sc = jnp.dot(qv, kb.T, preferred_element_type=jnp.float32)  # [Lr, P]
-    sc = jnp.where(ok, sc, NEG_INF)
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
-    p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_s[...] = acc_s[...] * alpha \
-        + jnp.dot(p, vb, preferred_element_type=jnp.float32)
-    m_s[...] = m_new
+    for g in range(kvh):  # GQA-native: head g's query rows vs its K/V
+        qv = q_ref[0, g]                               # [Lr, hd] (pre-scaled)
+        kb = k_ref[0, :, g, :].astype(jnp.float32)     # [P, hd]
+        vb = v_ref[0, :, g, :].astype(jnp.float32)
+        if quant:  # in-kernel dequant from the page's scale rows, oracle order
+            kb = kb * ks_ref[0, :, g, :]
+            vb = vb * vs_ref[0, :, g, :]
+        sc = jnp.dot(qv, kb.T, preferred_element_type=jnp.float32)  # [Lr, P]
+        sc = jnp.where(ok, sc, NEG_INF)
+        m_prev = m_s[g]
+        m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[g] = l_s[g] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_s[g] = acc_s[g] * alpha \
+            + jnp.dot(p, vb, preferred_element_type=jnp.float32)
+        m_s[g] = m_new
 
     @pl.when(p_idx == pps - 1)
     def _flush():
-        oacc_ref[0, 0, 0] = acc_s[...]
-        m_ref[0, 0, 0] = m_s[...][:, 0]
-        l_ref[0, 0, 0] = l_s[...][:, 0]
+        oacc_ref[0, 0] = acc_s[...]
+        m_ref[0, 0] = m_s[...]
+        l_ref[0, 0] = l_s[...]
 
 
 def _merge_splits(acc, m, l):
     """Standard flash-decode split merge (DESIGN.md §16):
-    acc [B, G, NS, Lr, hd]; m/l [B, G, NS, Lr] -> [B, G, Lr, hd]."""
-    m_star = m.max(axis=2)
-    w = jnp.exp(m - m_star[:, :, None, :])
-    l_star = (l * w).sum(axis=2)
-    out = (acc * w[..., None]).sum(axis=2)
-    return out / jnp.maximum(l_star, 1e-30)[..., None]
+    acc [B, NS, G, Lr, hd]; m/l [B, NS, G, Lr, 1] -> [B, G, Lr, hd]."""
+    m_star = m.max(axis=1)
+    w = jnp.exp(m - m_star[:, None])
+    l_star = (l * w).sum(axis=1)
+    out = (acc * w).sum(axis=1)
+    return out / jnp.maximum(l_star, 1e-30)
 
 
 def _flash_pallas(q, pool, page_table, kv_len, window, splits, interpret):
@@ -218,48 +221,58 @@ def _flash_pallas(q, pool, page_table, kv_len, window, splits, interpret):
         b, lanes, kvh, rep, hd).transpose(0, 2, 1, 3, 4).reshape(
         b, kvh, lr, hd)
 
-    def page_map(bi, g, s, p, pt_ref, kl_ref):
+    def page_map(bi, s, p, pt_ref, kl_ref):
         # the scalar-prefetched table IS the gather: this grid step's
-        # K/V block is the physical page the sequence's table names
-        return (pt_ref[bi, s * pps + p], 0, g, 0)
+        # K/V block is the physical page the sequence's table names — the
+        # whole page, all KV heads, so the block's minor dims (KVH, hd) are
+        # the pool's own and the TPU tiling accepts them
+        return (pt_ref[bi, s * pps + p], 0, 0, 0)
 
+    page = (1, page_size, kvh, hd)
     in_specs = [
-        pl.BlockSpec((1, 1, lr, hd), lambda bi, g, s, p, *_: (bi, g, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd), page_map),
-        pl.BlockSpec((1, page_size, 1, hd), page_map),
+        pl.BlockSpec((1, kvh, lr, hd), lambda bi, s, p, *_: (bi, 0, 0, 0)),
+        pl.BlockSpec(page, page_map),
+        pl.BlockSpec(page, page_map),
     ]
     inputs = [qr, pool["k"], pool["v"]]
+    kv_bytes = 2 * page_size * kvh * hd * pool["k"].dtype.itemsize
     if quant:
-        in_specs += [pl.BlockSpec((1, page_size, 1, 1), page_map)] * 2
+        in_specs += [pl.BlockSpec((1, page_size, kvh, 1), page_map)] * 2
         inputs += [pool["k_scale"], pool["v_scale"]]
+        kv_bytes += 2 * page_size * kvh * 4
 
+    part = lambda bi, s, p, *_: (bi, s, 0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, ns, pps),
+        grid=(b, ns, pps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, 1, lr, hd),
-                         lambda bi, g, s, p, *_: (bi, g, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, lr),
-                         lambda bi, g, s, p, *_: (bi, g, s, 0)),
-            pl.BlockSpec((1, 1, 1, lr),
-                         lambda bi, g, s, p, *_: (bi, g, s, 0)),
+            pl.BlockSpec((1, 1, kvh, lr, hd), part),
+            pl.BlockSpec((1, 1, kvh, lr, 1), part),
+            pl.BlockSpec((1, 1, kvh, lr, 1), part),
         ],
         scratch_shapes=[
-            pltpu.VMEM((lr, hd), jnp.float32),
-            pltpu.VMEM((lr, 1), jnp.float32),
-            pltpu.VMEM((lr, 1), jnp.float32),
+            pltpu.VMEM((kvh, lr, hd), jnp.float32),
+            pltpu.VMEM((kvh, lr, 1), jnp.float32),
+            pltpu.VMEM((kvh, lr, 1), jnp.float32),
         ],
     )
+    # q block + output blocks (double-buffered) + accumulator scratch, the
+    # page blocks, and one head's [Lr, P] score temporaries
+    rows_bytes = kvh * lr * (hd + 2) * 4
+    need = 5 * rows_bytes + 2 * kv_bytes + 4 * lr * page_size * 4
     acc, m, l = pl.pallas_call(
         functools.partial(_flash_kernel, page_size=page_size, rep=rep,
                           pps=pps, window=window, quant=quant),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kvh, ns, lr, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, ns, lr), jnp.float32),
-            jax.ShapeDtypeStruct((b, kvh, ns, lr), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, kvh, lr, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, kvh, lr, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, kvh, lr, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(need)),
         interpret=interpret,
     )(pt, kl, *inputs)
     out = _merge_splits(acc, m, l)                     # [B, KVH, Lr, hd]

@@ -6,13 +6,33 @@ through prefill + decode.
 """
 import argparse
 import dataclasses
+import os
+import pathlib
 
 import jax
 
 from repro.configs import registry
 from repro.core.linear import SparsityConfig
-from repro.models import model as M
 from repro.runtime import serve_loop
+
+
+# fixed, so that every run from this checkout finds the previous run's
+# executables (the directory is part of the cache's key); ignored by git
+CHECKOUT_CACHE = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def compile_cache_dir() -> str:
+    """Where the serving entry points keep JAX's persistent compilation
+    cache: ``JAX_COMPILATION_CACHE_DIR`` when it is set, else
+    ``CHECKOUT_CACHE``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CHECKOUT_CACHE)
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on at ``compile_cache_dir()``."""
+    path = compile_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main(argv=None):
@@ -90,8 +110,10 @@ def main(argv=None):
             pattern=tuple(args.sparse), mode="compressed",
             recipe=args.precision, act_quant=args.act_quant))
 
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    params = serve_loop.pack_params(params, cfg)
+    enable_compile_cache()
+    # init + pack one scanned unit at a time: the dense and the packed
+    # model never sit on the device together
+    params = serve_loop.init_packed(cfg, jax.random.PRNGKey(0))
 
     batch = {"tokens": jax.random.randint(
         jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0,

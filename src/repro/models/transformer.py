@@ -121,17 +121,31 @@ def _unit_init(cfg: ModelConfig, key) -> dict[str, Any]:
     return unit
 
 
-def init(cfg: ModelConfig, key) -> dict[str, Any]:
+def init_keys(cfg: ModelConfig, key):
+    """The key split ``init`` uses: (embed key, lm_head key, [U] unit keys).
+    ``init_units(cfg, unit_keys[u:u+1])`` rebuilds unit ``u`` alone."""
     ke, kh, ku = jax.random.split(key, 3)
-    unit_keys = jax.random.split(ku, cfg.num_units)
-    units = jax.vmap(lambda k: _unit_init(cfg, k))(unit_keys)
+    return ke, kh, jax.random.split(ku, cfg.num_units)
+
+
+def init_units(cfg: ModelConfig, unit_keys) -> dict[str, Any]:
+    """Stacked [len(unit_keys), ...] parameters of the scanned units."""
+    return jax.vmap(lambda k: _unit_init(cfg, k))(unit_keys)
+
+
+def init_outer(cfg: ModelConfig, ke, kh) -> dict[str, Any]:
+    """Everything outside the scanned units: embedding, final norm, head."""
     return {
         "embed": layers.embed_init(ke, cfg.vocab_size, cfg.d_model,
                                    _dtype(cfg)),
-        "units": units,
         "final_norm": layers.rmsnorm_init(cfg.d_model),
         "lm_head": sl.init(kh, cfg.d_model, cfg.vocab_size, _dtype(cfg)),
     }
+
+
+def init(cfg: ModelConfig, key) -> dict[str, Any]:
+    ke, kh, unit_keys = init_keys(cfg, key)
+    return {**init_outer(cfg, ke, kh), "units": init_units(cfg, unit_keys)}
 
 
 # --------------------------------------------------------------- forward

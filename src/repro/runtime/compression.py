@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -63,9 +62,9 @@ def compressed_crosspod_mean(grad: jax.Array, err: jax.Array, mesh,
             total = total + _dequant_block(qg[i], sg[i], g.shape)
         return (total / npod).astype(g.dtype), new_err
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     return fn(grad, err)
 
 

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -68,8 +67,8 @@ def gpipe_apply(stage_fn, stage_params, x, *, mesh, axis: str = "stage",
 
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 P())
-    fn = shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     outs = fn(stage_params, mbs)
     return outs.reshape((b,) + x.shape[1:])
 

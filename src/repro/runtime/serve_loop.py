@@ -28,7 +28,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -87,6 +86,44 @@ def pack_params(params: dict[str, Any], cfg: ModelConfig) -> dict[str, Any]:
         return node
 
     return walk(params)
+
+
+def init_packed(cfg: ModelConfig, key) -> dict[str, Any]:
+    """``pack_params(M.init(cfg, key), cfg)`` — the same arrays, bit for
+    bit — built one scanned unit at a time, so it fits where the dense and
+    the packed model do not fit together (a 4B model at 6:8 on one 16 GB
+    chip: 7.9 GB dense bf16 + 8.9 GB packed).
+
+    Each unit is initialised from its own key of ``transformer.init_keys``,
+    packed, and written into a preallocated stacked packed tree whose
+    buffers are donated to every write; its dense weights and the
+    compression temporaries are freed before the next unit.  Peak device
+    memory is the packed model plus one unit's dense weights and
+    temporaries.  Stacks that ``pack_params`` leaves dense get the same
+    one-unit-at-a-time initialisation (a bounded init peak); encoder-
+    decoder models are initialised and packed whole."""
+    if cfg.is_encoder_decoder:
+        return pack_params(M.init(cfg, key), cfg)
+    from repro.models import transformer as T
+
+    ke, kh, unit_keys = T.init_keys(cfg, key)
+    # initialised op by op exactly as M.init does (jit may round the
+    # random draws differently); packing is jitted, which keeps its
+    # temporaries fused
+    pack = jax.jit(lambda p: pack_params(p, cfg))
+
+    def pack_units(k):
+        return pack(T.init_units(cfg, k))
+
+    shapes = jax.eval_shape(pack_units, unit_keys[:1])
+    units = jax.tree_util.tree_map(
+        lambda s: jnp.zeros((cfg.num_units,) + s.shape[1:], s.dtype), shapes)
+    put = jax.jit(lambda stack, one, u: jax.tree_util.tree_map(
+        lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, u, 0),
+        stack, one), donate_argnums=0)
+    for u in range(cfg.num_units):
+        units = put(units, pack_units(unit_keys[u:u + 1]), u)
+    return {**pack(T.init_outer(cfg, ke, kh)), "units": units}
 
 
 def generate(params, cfg: ModelConfig, batch, max_new_tokens: int,
@@ -432,28 +469,28 @@ class ServeEngine:
             logits_spec = P(None, "tp")  # lm_head column-parallel on vocab
             # sampled ids are replicated (argmax_tokens all-gathers the
             # per-shard winners), so their out-spec is P() like any scalar
-            self._prefill_fn = jax.jit(shard_map(
+            self._prefill_fn = jax.jit(jax.shard_map(
                 prefill_step, mesh=self.mesh,
                 in_specs=(pspecs, rep, cspecs, rep, rep, rep, rep, rep),
-                out_specs=(rep, logits_spec, cspecs), check_rep=False))
-            self._decode_fn = jax.jit(shard_map(
+                out_specs=(rep, logits_spec, cspecs), check_vma=False))
+            self._decode_fn = jax.jit(jax.shard_map(
                 decode_step, mesh=self.mesh,
                 in_specs=(pspecs, rep, cspecs, rep, rep, rep),
-                out_specs=(rep, logits_spec, cspecs), check_rep=False))
+                out_specs=(rep, logits_spec, cspecs), check_vma=False))
             if self.ecfg.speculate > 0:
                 # verify logits are [B, K+1, V]: vocab still column-
                 # parallel, one extra replicated lane axis in the middle
-                self._verify_fn = jax.jit(shard_map(
+                self._verify_fn = jax.jit(jax.shard_map(
                     verify_step, mesh=self.mesh,
                     in_specs=(pspecs, rep, cspecs, rep, rep, rep, rep),
                     out_specs=(rep, P(None, None, "tp"), cspecs),
-                    check_rep=False))
+                    check_vma=False))
             # COW page copies are per-shard elementwise on the head-sharded
             # pools; the host-decided (src, dst) pairs replicate, so every
             # shard copies the same page structure (DESIGN.md §11)
-            self._cow_fn = jax.jit(shard_map(
+            self._cow_fn = jax.jit(jax.shard_map(
                 copy_step, mesh=self.mesh, in_specs=(cspecs, rep, rep),
-                out_specs=cspecs, check_rep=False))
+                out_specs=cspecs, check_vma=False))
         else:
             self._prefill_fn = jax.jit(prefill_step)
             self._decode_fn = jax.jit(decode_step)
@@ -516,10 +553,7 @@ class ServeEngine:
             self.params, np.zeros((1, ec.prefill_chunk), np.int32),
             self.cache, ptab[:1], np.int32(0), np.int32(ec.prefill_chunk),
             np.int32(0), np.bool_(True)))
-        jax.block_until_ready(self._decode_fn(
-            self.params, self._put_tok(np.zeros((ec.max_batch,), np.int32)),
-            self.cache, ptab, np.zeros((ec.max_batch,), np.int32),
-            np.zeros((ec.max_batch,), bool)))
+        jax.block_until_ready(self._decode_fn(*self._dummy_decode_args()))
         n = self._cow_lanes
         # all lanes carry the out-of-bounds dst id: every write is dropped
         jax.block_until_ready(self._cow_fn(
@@ -541,6 +575,40 @@ class ServeEngine:
                 f"{name} step compiled {fn._cache_size()} times in warmup"
         self.stats.warmup_s = time.time() - t0
         return self.stats.warmup_s
+
+    def _dummy_decode_args(self):
+        """Decode-step inputs with every slot inactive (no write lands)."""
+        b = self.ecfg.max_batch
+        return (self.params, self._put_tok(np.zeros((b,), np.int32)),
+                self.cache, self.kv.page_table_array(),
+                np.zeros((b,), np.int32), np.zeros((b,), bool))
+
+    def decode_hlo(self) -> str:
+        """Optimized HLO text of the compiled decode step — what runs on the
+        device, e.g. to check that its kernels are Pallas custom calls.
+        Compiles the step again unless a persistent compilation cache
+        holds it."""
+        return self._decode_fn.lower(
+            *self._dummy_decode_args()).compile().as_text()
+
+    def prefill_logits(self, prompt: list[int]) -> np.ndarray:
+        """Logits ``[V]`` at the last token of ``prompt`` (at most one
+        prefill chunk), computed by the compiled prefill step as a fresh
+        sequence over pages ``0..``; the cache it writes is discarded and
+        no state changes, as in ``warmup``.  For comparing engines that
+        serve the same model under different kernels or recipes."""
+        ec = self.ecfg
+        if not 0 < len(prompt) <= ec.prefill_chunk:
+            raise ValueError(f"prompt of {len(prompt)} tokens does not fit "
+                             f"one prefill chunk of {ec.prefill_chunk}")
+        chunk = np.zeros((1, ec.prefill_chunk), np.int32)
+        chunk[0, :len(prompt)] = prompt
+        maxp = self.kv.page_table_array().shape[1]
+        pages = (np.arange(maxp, dtype=np.int32) % ec.num_pages)[None]
+        _, logits, _ = self._prefill_fn(
+            self.params, chunk, self.cache, pages, np.int32(0),
+            np.int32(len(prompt)), np.int32(0), np.bool_(True))
+        return np.asarray(logits[0], np.float32)
 
     # ------------------------------------------------------------ intake
     def submit(self, prompt: list[int], max_new_tokens: int,

@@ -1,7 +1,7 @@
 """Tensor-parallel serving context + partitioning rules (DESIGN.md §9).
 
 The serving engine runs its two jitted step functions under
-``jax.experimental.shard_map`` over a 1-D ``('tp',)`` mesh.  This module
+``jax.shard_map`` over a 1-D ``('tp',)`` mesh.  This module
 owns everything TP-specific:
 
 * :class:`TPContext` / :func:`activate` — a thread-local marker that the
@@ -21,15 +21,16 @@ owns everything TP-specific:
   ==================  =========================================  =========
 
   Compressed operands (``values``/``indices``, the packed (2N-2):2N
-  blocks) shard exactly like their dense ``w``: the compressed layout is
-  group-major (K/L groups of w·M slots), so a row-parallel K-slice is a
-  contiguous block-slice and every device holds *only its shard* of the
-  packed blocks — see ``compressed.split_k``.  Nibble-packed 'w4' values
-  (DESIGN.md §10) shard on the same dim: every window group holds an even
-  slot count, so shard boundaries stay byte-aligned and byte slices are
-  congruent with slot slices.  Quantized recipes stay parity with the
-  unsharded engine because row-parallel activation quantization uses the
-  :func:`reduce_max` global absmax (see ``linear.apply``).
+  blocks) shard on the same logical dims as their dense ``w``: the
+  compressed layout is slot-planar ``[P, K/L, out]``, so a row-parallel
+  K-slice is a contiguous slice of the group axis, a column-parallel slice
+  one of the trailing ``out`` axis, and every device holds *only its
+  shard* of the packed blocks — see ``compressed.split_k``/``split_out``.
+  Nibble-packed 'w4' values (DESIGN.md §10) pair slot planes within a
+  byte, so they shard on exactly the same dims.  Quantized recipes stay
+  parity with the unsharded engine because row-parallel activation
+  quantization uses the :func:`reduce_max` global absmax (see
+  ``linear.apply``).
 * :func:`validate` — fail-fast divisibility checks (heads, d_ff, vocab,
   SSM heads, and pattern-group alignment of row-parallel K shards).
 * :func:`rmsnorm` — TP-aware gated-RMSNorm for activations sharded on
@@ -200,19 +201,22 @@ def _p(spec) -> P:
 
 def _proj_spec(leaf_name: str, nd: int, role: str) -> P:
     """Spec for one leaf of a projection dict ({'w'} | {'values','indices'}
-    | {'w_slided'} [+ 's_w']).  Layout is [..., out, K-like]; column
-    parallelism shards ``out`` (dim nd-2), row parallelism shards the
-    K-like dim (dim nd-1; for compressed operands that is the group-major
-    packed dim, which slices congruently with K)."""
+    | {'w_slided'} [+ 's_w']).  Dense and slided layouts are [..., out,
+    K-like]; compressed operands are slot-planar [..., P, K/L, out].
+    Column parallelism shards ``out``, row parallelism the K-like dim (for
+    compressed operands the group axis K/L, which slices congruently with
+    K)."""
     spec: list = [None] * nd
+    out_dim, k_dim = ((nd - 1, nd - 2) if leaf_name in ("values", "indices")
+                      else (nd - 2, nd - 1))
     if leaf_name == "s_w":  # [..., out, 1] row scales
         if role == "col":
             spec[nd - 2] = AXIS
         return _p(spec)
     if role == "col":
-        spec[nd - 2] = AXIS
+        spec[out_dim] = AXIS
     elif role == "row":
-        spec[nd - 1] = AXIS
+        spec[k_dim] = AXIS
     return _p(spec)
 
 

@@ -92,6 +92,17 @@ def test_cache_disabled_with_empty_env(monkeypatch):
                     autotune.DEFAULT, 1.0)
 
 
+def test_cache_path_unset_means_no_disk_cache(monkeypatch, tmp_path):
+    """Served tiles come from committed code: with REPRO_AUTOTUNE_CACHE
+    unset nothing is read from or written to any file (no ~/.cache)."""
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE")
+    monkeypatch.setenv("HOME", str(tmp_path))
+    assert autotune.cache_path() is None
+    autotune.record(autotune.make_key("op", rows=1, m=1, k=1),
+                    autotune.TileConfig(bm=128), 1.0)
+    assert list(tmp_path.iterdir()) == []
+
+
 def test_autotune_picks_fastest_candidate():
     slow = autotune.TileConfig(bm=128)
     fast = autotune.TileConfig(bm=256)

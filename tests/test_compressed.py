@@ -43,7 +43,8 @@ def test_zero_storage_overhead(n, groups, seed):
     w = _pattern_weights(rng, 3, groups, pat)
     c = comp.compress(packer.pack_slided(w, dec), dec)
     k = w.shape[-1]
-    assert c.values.shape[-1] == dec.compressed_len(k)
+    assert c.slots == dec.compressed_len(k)
+    assert c.values.size == w.shape[0] * dec.compressed_len(k)
     assert dec.compressed_len(k) == int(k * pat.density)
 
 

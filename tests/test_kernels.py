@@ -176,9 +176,10 @@ def test_decompress_tile_matches_decompress_original():
         rng = np.random.default_rng(n)
         w = _weights(rng, 8, 8 * dec.source.l, dec.source)
         c = comp.compress(packer.pack_slided(w, dec), dec)
-        out = decompress_tile(c.values, c.indices, n)
+        out = decompress_tile(c.values, c.indices, n)  # [L, G, out]
+        dense = np.asarray(comp.decompress_original(c))  # [out, G*L]
         np.testing.assert_array_equal(
-            np.asarray(out), np.asarray(comp.decompress_original(c)))
+            np.asarray(out), dense.reshape(8, -1, 2 * n).transpose(2, 1, 0))
 
 
 @pytest.mark.parametrize("pattern", PATTERNS)

@@ -131,7 +131,7 @@ def test_w4_packed_compression_and_shard_mirrors(n, shards, seed):
     w = _weights(rng, out, k, dec.source)
     q4 = quant.quantize_weight_int4_rowwise(w)
     c = comp.compress(packer.pack_slided(q4.q, dec), dec, pack_values=True)
-    assert c.packed and c.values.shape[-1] * 2 == c.indices.shape[-1]
+    assert c.packed and c.values.shape[-3] * 2 == c.indices.shape[-3]
     full = np.asarray(comp.decompress_original(c))
     np.testing.assert_array_equal(full, np.asarray(q4.q))
     for i, sh in enumerate(comp.split_k(c, shards)):
@@ -272,7 +272,7 @@ def test_prepare_w4_emits_packed_values():
     params = linear.init(jax.random.PRNGKey(0), 48, 24)
     cfg = SparsityConfig(pattern=(6, 8), mode="compressed", recipe="w4")
     prepared = linear.prepare(params, cfg)
-    assert prepared["values"].shape[-1] * 2 == prepared["indices"].shape[-1]
+    assert prepared["values"].shape[-3] * 2 == prepared["indices"].shape[-3]
     int8_cfg = SparsityConfig(pattern=(6, 8), mode="compressed",
                               recipe="int8")
     int8_prep = linear.prepare(params, int8_cfg)

@@ -288,9 +288,9 @@ def test_paged_engine_quantized_recipe_parity(recipe, n_family):
 
 def test_pack_params_packs_stacked_unit_weights():
     """Load-time compression covers the scanned [U, out, K] unit
-    projections, not just 2-D leaves (lm_head): a lazy in-trace prepare
-    would quantize per-shard K-slices under TP and break recipe parity
-    (DESIGN.md §10)."""
+    projections (stored slot-planar, [U, P, K/L, out]), not just 2-D
+    leaves (lm_head): a lazy in-trace prepare would quantize per-shard
+    K-slices under TP and break recipe parity (DESIGN.md §10)."""
     cfg = registry.smoke_config("h2o-danube-3-4b")
     cfg = dataclasses.replace(cfg, d_model=48, num_heads=4, num_kv_heads=2,
                               head_dim=12, d_ff=96, num_layers=2)
@@ -301,9 +301,33 @@ def test_pack_params_packs_stacked_unit_weights():
     for name in ("wq", "wo"):
         leaf = unit["mixer"][name]
         assert set(leaf) == {"values", "indices", "s_w"}, name
-        assert leaf["values"].ndim == 3  # [U, out, packed-K]
+        assert leaf["values"].ndim == 4  # [U, slot planes, K/L, out]
     assert set(unit["ffn"]["w_down"]) == {"values", "indices", "s_w"}
     assert set(packed["lm_head"]) == {"values", "indices", "s_w"}
+
+
+@pytest.mark.parametrize("mode,recipe", [("compressed", "none"),
+                                         ("compressed", "int8"),
+                                         ("compressed", "w4"),
+                                         ("masked", "none")])
+def test_init_packed_matches_pack_params_bitwise(mode, recipe):
+    """init_packed builds the model one scanned unit at a time (so the
+    dense and the packed full-size model never share a device) and must
+    yield exactly pack_params(M.init(...)): same tree, shapes, dtypes and
+    bits."""
+    cfg = dataclasses.replace(
+        registry.smoke_config("h2o-danube-3-4b"),
+        sparsity=SparsityConfig(pattern=(6, 8), mode=mode, recipe=recipe))
+    key = jax.random.PRNGKey(7)
+    want = serve_loop.pack_params(M.init(cfg, key), cfg)
+    got = serve_loop.init_packed(cfg, key)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                      np.asarray(b).view(np.uint8))
 
 
 def test_engine_warmup_compiles_without_touching_state():
