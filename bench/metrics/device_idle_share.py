@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device op intervals / window) (%)."""
+
+
+def read(run, trace):
+    return 100.0 * (1.0 - trace.busy_s / trace.window_s)
