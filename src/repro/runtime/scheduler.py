@@ -86,6 +86,26 @@ REASON_INVARIANT = "invariant_violation"  # watchdog quarantine
 
 
 @dataclasses.dataclass
+class Timing:
+    """Lifecycle stamps of one request on the scheduler's ``time_fn``
+    clock, each taken the first time it happens: a request requeued after
+    an eviction keeps its first stamps.  ``None`` until it happens.
+
+    ``t_first_chunk - t_submit`` is the wait for a prefill turn;
+    ``t_first_token - t_first_chunk`` is the prefill itself, with the
+    decode steps interleaved between its chunks."""
+    t_submit: float | None = None       # Scheduler.submit
+    t_admit: float | None = None        # first admission to a decode slot
+    t_first_chunk: float | None = None  # first PrefillChunk decided for it
+    t_first_token: float | None = None  # first output token appended
+
+    def stamp(self, name: str, clock) -> None:
+        """Set stamp ``name`` from ``clock()`` unless it is already set."""
+        if getattr(self, name) is None:
+            setattr(self, name, clock())
+
+
+@dataclasses.dataclass
 class Request:
     rid: int
     prompt: list[int]
@@ -106,18 +126,22 @@ class Request:
     deadline_step: int | None = None
     # ... and the absolute wall-clock instant (scheduler ``time_fn`` units)
     deadline_t: float | None = None
+    # shared by the requeued copies made after an eviction
+    timing: Timing = dataclasses.field(default_factory=Timing, compare=False)
 
 
 @dataclasses.dataclass(frozen=True)
 class Finished:
-    """Terminal record of one request: how it left the scheduler and the
+    """Terminal record of one request: how it left the scheduler, the
     greedy tokens it produced before leaving (partial for non-OK exits,
-    empty for requests that never reached a decode slot)."""
+    empty for requests that never reached a decode slot) and its
+    lifecycle stamps."""
     rid: int
     status: str                 # OK | TIMEOUT | CANCELLED | REJECTED | FAILED
     reason: str | None
     tokens: tuple[int, ...]
     evictions: int = 0
+    timing: Timing = dataclasses.field(default_factory=Timing, compare=False)
 
 
 @dataclasses.dataclass
@@ -402,6 +426,7 @@ class Scheduler:
         """Enqueue ``req``.  Returns None on acceptance, else the typed
         rejection reason (also recorded as a REJECTED terminal in
         :attr:`finished`) — client input never raises (DESIGN.md §12)."""
+        req.timing.stamp("t_submit", self.time_fn)
         if len(req.prompt) + req.max_new_tokens > self.cfg.max_seq_len or \
                 self.cfg.pages_for(len(req.prompt) + req.max_new_tokens) \
                 > self.cfg.num_pages:
@@ -459,7 +484,7 @@ class Scheduler:
             self.kv.free_slot(seq.slot)
         self.finished.append(Finished(
             seq.rid, status, reason, tuple(self.full_output(seq)),
-            self.evict_counts.get(seq.rid, 0)))
+            self.evict_counts.get(seq.rid, 0), seq.req.timing))
         if status != OK:
             self.trace.append(f"{status.lower()} r{seq.rid}({reason})")
 
@@ -471,7 +496,7 @@ class Scheduler:
         prior = self._requeued_outputs.get(req.rid, [])
         self.finished.append(Finished(
             req.rid, status, reason, tuple(prior),
-            self.evict_counts.get(req.rid, 0)))
+            self.evict_counts.get(req.rid, 0), req.timing))
         self.trace.append(f"{status.lower()} r{req.rid}({reason})")
 
     def _reject(self, req: Request, reason: str) -> str:
@@ -552,6 +577,7 @@ class Scheduler:
             del self.waiting[idx]
             self.running.append(seq)
             self.stats.admitted += 1
+            req.timing.stamp("t_admit", self.time_fn)
             if not req.requeued:
                 self.stats.queue_wait_steps.append(
                     max(0, self.clock - req.arrival))
@@ -720,6 +746,7 @@ class Scheduler:
             self.stats.prefill_tokens += length - rec
             self.stats.prefill_chunks += 1
             self._last_was_prefill = True
+            seq.req.timing.stamp("t_first_chunk", self.time_fn)
             self.trace.append(f"prefill r{seq.rid}[{start}:{start + length}]")
             return PrefillChunk(seq, start, length, self._record_cow(cow))
         if decoding:
@@ -836,7 +863,7 @@ class Scheduler:
         for seq, tok in zip(batch.seqs, tokens):
             if seq not in self.running:
                 continue
-            seq.out_tokens.append(int(tok))
+            self.append_token(seq, int(tok))
 
     def _propose(self, seq: Sequence) -> tuple[int, ...]:
         """Draft tokens for one sequence, capped so the verify step can
@@ -870,6 +897,7 @@ class Scheduler:
 
     def append_token(self, seq: Sequence, token: int) -> None:
         seq.out_tokens.append(token)
+        seq.req.timing.stamp("t_first_token", self.time_fn)
 
     def completed_verify(self, batch: VerifyBatch,
                          results: list[tuple[int, list[int]]]) -> None:
@@ -887,7 +915,7 @@ class Scheduler:
             if seq not in self.running:   # quarantined/cancelled mid-step
                 continue
             for t in emitted:
-                seq.out_tokens.append(int(t))
+                self.append_token(seq, int(t))
             self.stats.decode_tokens += len(emitted)
             self.stats.accepted_tokens += n_acc
             self.kv.truncate(seq.slot, seq.kv_len - 1)

@@ -37,6 +37,7 @@ from repro.runtime import draft as draft_mod
 from repro.runtime import faults as fl
 from repro.runtime.kv_cache import KVCacheManager, PagedKVConfig
 from repro.runtime import scheduler as sch
+from repro.runtime import spans
 from repro.runtime.scheduler import (DecodeBatch, PrefillChunk, Request,
                                      Scheduler, VerifyBatch, make_policy)
 from repro.sharding import tp as tpmod
@@ -233,13 +234,19 @@ class Completion:
     ``status`` is one of ``OK | TIMEOUT | CANCELLED | REJECTED | FAILED``;
     non-OK completions carry a typed ``reason`` from the scheduler's
     failure taxonomy and keep whatever tokens were generated before the
-    exit (a TIMEOUT/CANCELLED stream is a prefix of the fault-free one)."""
+    exit (a TIMEOUT/CANCELLED stream is a prefix of the fault-free one).
+
+    ``timing`` holds the request's lifecycle stamps on the scheduler's
+    clock (:class:`~repro.runtime.scheduler.Timing`: submitted, admitted,
+    first prefill chunk decided, first token appended)."""
     rid: int
     prompt: list[int]
     tokens: list[int]
     evictions: int = 0
     status: str = sch.OK
     reason: str | None = None
+    timing: sch.Timing = dataclasses.field(default_factory=sch.Timing,
+                                           compare=False)
 
     @property
     def ok(self) -> bool:
@@ -325,6 +332,17 @@ class EngineStats:
         """Aggregate decode throughput normalized by the TP mesh size —
         the per-chip number the paper's multi-GPU tables report."""
         return self.decode_tok_s / max(self.tp, 1)
+
+
+def _tag(sp, decision) -> None:
+    """Write what a step executes into its ``engine.step`` span."""
+    if decision is None:
+        sp.set_metadata(kind="none", lanes=0)
+    elif isinstance(decision, PrefillChunk):
+        sp.set_metadata(kind="prefill", lanes=1, rid=decision.seq.rid)
+    else:
+        kind = "verify" if isinstance(decision, VerifyBatch) else "decode"
+        sp.set_metadata(kind=kind, lanes=len(decision.seqs))
 
 
 class ServeEngine:
@@ -429,7 +447,9 @@ class ServeEngine:
         # instead of [B, vocab] float32 logits, or thread the device-
         # resident ids straight into the next decode dispatch (DESIGN.md
         # §15).  Both outputs always exist — ``device_sample`` only picks
-        # which one the host fetches, so the flag never retraces.
+        # which one the host fetches, so the flag never retraces.  The
+        # closures' names are spans.PREFILL_STEP, DECODE_STEP, COPY_STEP
+        # and VERIFY_STEP: a trace finds each compiled step by them.
         def prefill_step(p, tok, c, pt, start, rlen, slot, reset):
             with tpmod.activate(ntp):
                 logits, c = M.paged_prefill_chunk(p, cfg, tok, c, pt, start,
@@ -630,21 +650,23 @@ class ServeEngine:
             raise ValueError("max_new_tokens must be >= 1")
         if not prompt:
             raise ValueError("prompt must be non-empty")
-        self._prompts[rid] = list(prompt)
-        # block hashing at enqueue (DESIGN.md §11): the chained full-page
-        # hashes ride the request so admission can probe the prefix index
-        hashes = (self.kv.hashes_for(prompt)
-                  if self.ecfg.prefix_cache else None)
-        dstep = (arrival + deadline_steps
-                 if deadline_steps is not None else None)
-        dt = (time.monotonic() + deadline_s
-              if deadline_s is not None else None)
-        self.sched.submit(Request(rid=rid, prompt=list(prompt),
-                                  max_new_tokens=max_new_tokens,
-                                  arrival=arrival, eos_id=eos_id,
-                                  priority=priority, block_hashes=hashes,
-                                  deadline_step=dstep, deadline_t=dt))
-        self._drain_finished()  # surface immediate rejection/shed
+        with spans.span(spans.SUBMIT, rid=rid):
+            self._prompts[rid] = list(prompt)
+            # block hashing at enqueue (DESIGN.md §11): the chained full-
+            # page hashes ride the request so admission can probe the
+            # prefix index
+            hashes = (self.kv.hashes_for(prompt)
+                      if self.ecfg.prefix_cache else None)
+            dstep = (arrival + deadline_steps
+                     if deadline_steps is not None else None)
+            dt = (time.monotonic() + deadline_s
+                  if deadline_s is not None else None)
+            self.sched.submit(Request(rid=rid, prompt=list(prompt),
+                                      max_new_tokens=max_new_tokens,
+                                      arrival=arrival, eos_id=eos_id,
+                                      priority=priority, block_hashes=hashes,
+                                      deadline_step=dstep, deadline_t=dt))
+            self._drain_finished()  # surface immediate rejection/shed
         return rid
 
     def cancel(self, rid: int) -> bool:
@@ -677,7 +699,8 @@ class ServeEngine:
         stamps ``_t_ready``: the fetch returning means the device has
         drained its queue, so host time from here to the next dispatch is
         device-idle gap (``stats.host_gap_s``)."""
-        arr = np.asarray(x)
+        with spans.span(spans.FETCH, bytes=x.nbytes):
+            arr = np.asarray(x)
         self.stats.d2h_bytes += arr.nbytes
         self._t_ready = time.time()
         return arr
@@ -709,7 +732,8 @@ class ServeEngine:
         for fin in self.sched.take_finished():
             comp = Completion(fin.rid, self._prompts.get(fin.rid, []),
                               list(fin.tokens), fin.evictions,
-                              status=fin.status, reason=fin.reason)
+                              status=fin.status, reason=fin.reason,
+                              timing=fin.timing)
             self.completions[fin.rid] = comp
             out.append(comp)
         return out
@@ -746,8 +770,7 @@ class ServeEngine:
         retrying is always safe.  Exhausting ``step_retries`` re-raises
         for the caller to fail the decision's requests."""
         if self.injector is None:
-            self._note_dispatch()
-            return fn(*args)
+            return self._call(fn, *args)
         attempts = self.ecfg.step_retries + 1
         for attempt in range(attempts):
             if self.injector.fire("step"):
@@ -760,6 +783,12 @@ class ServeEngine:
                 if self.ecfg.retry_backoff_s:
                     self._backoff_wait(attempt)
                 continue
+            return self._call(fn, *args)
+
+    def _call(self, fn, *args):
+        """Hand the device one model step: the jitted call returns once
+        the step is enqueued and its numpy inputs are on the device."""
+        with spans.span(spans.DISPATCH):
             self._note_dispatch()
             return fn(*args)
 
@@ -799,53 +828,66 @@ class ServeEngine:
         async-on traces bitwise identical to async-off.  Fault injection
         disables the fast path outright (``injector`` is not None): the
         lookahead's allocation calls would otherwise shift the
-        deterministic per-site fault schedule."""
-        self.stats.steps += 1
-        if self.ecfg.async_loop and self._pending is not None:
-            la = (self.sched.lookahead_decode(self._pending[0])
-                  if self.injector is None else None)
-            if la is not None:
-                return self._threaded_decode(la)
-            # slow path: land the in-flight tokens first so next_decision
-            # sees the post-step state (retire what the step finished)
-            self._apply_pending()
-            self.sched.retire_finished()
-        return self._sync_step()
+        deterministic per-site fault schedule.
+
+        The step runs inside the ``engine.step`` span and its phases
+        inside ``engine.schedule`` / ``prepare`` / ``dispatch`` /
+        ``apply`` (``runtime.spans``)."""
+        with spans.span(spans.STEP, step=self.stats.steps) as sp:
+            self.stats.steps += 1
+            if self.ecfg.async_loop and self._pending is not None:
+                with spans.span(spans.SCHEDULE):
+                    la = (self.sched.lookahead_decode(self._pending[0])
+                          if self.injector is None else None)
+                if la is not None:
+                    _tag(sp, la)
+                    return self._threaded_decode(la)
+                # slow path: land the in-flight tokens first so
+                # next_decision sees the post-step state (retire what the
+                # step finished)
+                with spans.span(spans.APPLY):
+                    self._apply_pending()
+                    self.sched.retire_finished()
+            return self._sync_step(sp)
 
     def _threaded_decode(self, la: DecodeBatch) -> list[Completion]:
         """Fast-path decode dispatch (DESIGN.md §15): step N+1 starts from
         step N's on-device token array before step N's results ever reach
         the host."""
         batch, ids_dev = self._pending
-        self._run_cow(la.cow)  # provably empty on this path (lookahead
-        #                        write pages are already exclusive)
-        bmax = self.ecfg.max_batch
-        kvl = np.zeros((bmax,), np.int32)
-        active = np.zeros((bmax,), bool)
-        for seq in la.seqs:
-            # tokens are not applied yet, so seq.kv_len is the PRE-apply
-            # length == post-apply kv_len - 1, the context-written count
-            # the decode step wants; inactive lanes of ids_dev carry
-            # whatever lane garbage step N computed — rows are batch-
-            # independent and masked writes drop them, same as the zero
-            # padding the synchronous path feeds
-            kvl[seq.slot] = seq.kv_len
-            active[seq.slot] = True
-        self._note_dispatch()
-        ids2, _logits, self.cache = self._decode_fn(
-            self.params, ids_dev, self.cache, self.kv.page_table_array(),
-            kvl, active)
+        with spans.span(spans.PREPARE):
+            self._run_cow(la.cow)  # provably empty on this path (lookahead
+            #                        write pages are already exclusive)
+            bmax = self.ecfg.max_batch
+            kvl = np.zeros((bmax,), np.int32)
+            active = np.zeros((bmax,), bool)
+            for seq in la.seqs:
+                # tokens are not applied yet, so seq.kv_len is the PRE-
+                # apply length == post-apply kv_len - 1, the context-
+                # written count the decode step wants; inactive lanes of
+                # ids_dev carry whatever lane garbage step N computed —
+                # rows are batch-independent and masked writes drop them,
+                # same as the zero padding the synchronous path feeds
+                kvl[seq.slot] = seq.kv_len
+                active[seq.slot] = True
+            ptab = self.kv.page_table_array()
+        ids2, _logits, self.cache = self._call(
+            self._decode_fn, self.params, ids_dev, self.cache, ptab, kvl,
+            active)
         self.stats.lookahead_steps += 1
         # overlap window: the device is running step N+1 while the host
         # fetches and applies step N here
-        self._apply_pending()
-        self._t_ready = None  # device holds queued work — not idle
-        self._pending = (la, ids2)
-        self.sched.retire_finished()  # no-op by lookahead precondition
-        return self._drain_finished()
+        with spans.span(spans.APPLY):
+            self._apply_pending()
+            self._t_ready = None  # device holds queued work — not idle
+            self._pending = (la, ids2)
+            self.sched.retire_finished()  # no-op by lookahead precondition
+            return self._drain_finished()
 
-    def _sync_step(self) -> list[Completion]:
-        decision = self.sched.next_decision()
+    def _sync_step(self, sp) -> list[Completion]:
+        with spans.span(spans.SCHEDULE):
+            decision = self.sched.next_decision()
+        _tag(sp, decision)
         if decision is None:
             # no executable work this tick (future arrivals, a voided
             # decision, or a deferred admission); clock has advanced
@@ -860,104 +902,119 @@ class ServeEngine:
             self.sched.fail(decision.seq, sch.REASON_POISONED)
             return self._drain_finished()
 
-        self._run_cow(decision.cow)
+        with spans.span(spans.PREPARE):
+            self._run_cow(decision.cow)
+            fn, args = self._inputs(decision)
         try:
-            if isinstance(decision, PrefillChunk):
-                seq, start, length = (decision.seq, decision.start,
-                                      decision.length)
-                chunk = seq.prompt[start:start + length]
-                chunk = chunk + [0] * (self.ecfg.prefill_chunk - length)
-                pt = self.kv.page_table_array()[seq.slot:seq.slot + 1]
-                ids, logits, self.cache = self._dispatch(
-                    self._prefill_fn, self.params,
-                    np.asarray([chunk], np.int32), self.cache,
-                    pt, np.int32(start), np.int32(length),
-                    np.int32(seq.slot), np.bool_(start == seq.resume_pos))
-                self.sched.completed_prefill(decision)
-                if not seq.prefilling:  # prompt done -> first token
-                    # mid-prompt chunks fetch NOTHING (pure dispatch);
-                    # the final chunk fetches [1] int32 — or the logits
-                    # row on the fallback path
-                    if self.ecfg.device_sample:
-                        tok = int(self._fetch(ids)[0])
-                    else:
-                        tok = self._sample(self._fetch(logits[0]))
-                    self.sched.append_token(seq, tok)
-            elif isinstance(decision, VerifyBatch):
-                bmax, lanes = self.ecfg.max_batch, self._verify_lanes
-                token = np.zeros((bmax, lanes), np.int32)
-                kvl = np.zeros((bmax,), np.int32)
-                rlen = np.ones((bmax,), np.int32)
-                active = np.zeros((bmax,), bool)
-                for seq, drft in zip(decision.seqs, decision.drafts):
-                    token[seq.slot, 0] = seq.out_tokens[-1]
-                    token[seq.slot, 1:1 + len(drft)] = drft
-                    kvl[seq.slot] = seq.kv_len - 1  # context written
-                    rlen[seq.slot] = 1 + len(drft)
-                    active[seq.slot] = True
-                ids, logits, self.cache = self._dispatch(
-                    self._verify_fn, self.params, token, self.cache,
-                    self.kv.page_table_array(), kvl, rlen, active)
-                if self.ecfg.device_sample:
-                    argmax_all = self._fetch(ids)     # [B, K+1] int32
-                else:
-                    # logits fallback: one batched argmax over the whole
-                    # [B, K+1, V] block (the former per-lane Python loop,
-                    # vectorized — same first-occurrence tie-breaking)
-                    argmax_all = np.argmax(self._fetch(logits), axis=-1)
-                results = []
-                for seq, drft in zip(decision.seqs, decision.drafts):
-                    # lane i's logits predict the token after lane i;
-                    # lanes past real_len are padding — never consulted
-                    argmax = [int(t) for t in
-                              argmax_all[seq.slot, :1 + len(drft)]]
-                    n_acc, emitted = draft_mod.accept_drafts(drft, argmax)
-                    eos = seq.req.eos_id
-                    if eos is not None and eos in emitted:
-                        # tokens after eos were never really generated;
-                        # if the cut drops the bonus token, every emitted
-                        # token is an accepted draft
-                        emitted = emitted[:emitted.index(eos) + 1]
-                        n_acc = min(n_acc, len(emitted))
-                    results.append((n_acc, emitted))
-                # appends tokens, counts accept stats, truncates rejected-
-                # suffix pages (KV rollback, DESIGN.md §14)
-                self.sched.completed_verify(decision, results)
-            else:
-                assert isinstance(decision, DecodeBatch)
-                bmax = self.ecfg.max_batch
-                token = np.zeros((bmax,), np.int32)
-                kvl = np.zeros((bmax,), np.int32)
-                active = np.zeros((bmax,), bool)
-                for seq in decision.seqs:
-                    token[seq.slot] = seq.out_tokens[-1]
-                    kvl[seq.slot] = seq.kv_len - 1  # context written
-                    active[seq.slot] = True
-                ids, logits, self.cache = self._dispatch(
-                    self._decode_fn, self.params, self._put_tok(token),
-                    self.cache, self.kv.page_table_array(), kvl, active)
-                if self.ecfg.async_loop:
-                    # defer the apply: tokens land at the next step() /
-                    # cancel() boundary, overlapped with host scheduling
-                    # (and possibly a threaded next dispatch) — §15
-                    self._pending = (decision, ids)
-                    return self._drain_finished()
-                if self.ecfg.device_sample:
-                    toks = self._fetch(ids)           # [B] int32
-                else:
-                    toks = np.argmax(self._fetch(logits), axis=-1)
-                for seq in decision.seqs:
-                    self.sched.append_token(seq, int(toks[seq.slot]))
+            ids, logits, self.cache = self._dispatch(fn, *args)
         except fl.TransientStepError:
             # retries exhausted: the device function never ran (injection
             # precedes dispatch), so page state is consistent — fail the
             # decision's requests and keep serving everyone else
+            ids = None
             doomed = ([decision.seq] if isinstance(decision, PrefillChunk)
                       else list(decision.seqs))
             for seq in doomed:
                 self.sched.fail(seq, sch.REASON_STEP_ERROR)
-        self.sched.retire_finished()
-        return self._drain_finished()
+        if ids is not None and self.ecfg.async_loop and isinstance(
+                decision, DecodeBatch):
+            # defer the apply: tokens land at the next step() / cancel()
+            # boundary, overlapped with host scheduling (and possibly a
+            # threaded next dispatch) — §15
+            self._pending = (decision, ids)
+            return self._drain_finished()
+        with spans.span(spans.APPLY):
+            if ids is not None:
+                self._land(decision, ids, logits)
+            self.sched.retire_finished()
+            return self._drain_finished()
+
+    def _inputs(self, decision):
+        """The jitted step that executes ``decision`` and its arguments,
+        built on the host from the scheduler's state."""
+        bmax = self.ecfg.max_batch
+        if isinstance(decision, PrefillChunk):
+            seq, start, length = decision.seq, decision.start, decision.length
+            chunk = seq.prompt[start:start + length]
+            chunk = chunk + [0] * (self.ecfg.prefill_chunk - length)
+            pt = self.kv.page_table_array()[seq.slot:seq.slot + 1]
+            return self._prefill_fn, (
+                self.params, np.asarray([chunk], np.int32), self.cache, pt,
+                np.int32(start), np.int32(length), np.int32(seq.slot),
+                np.bool_(start == seq.resume_pos))
+        kvl = np.zeros((bmax,), np.int32)
+        active = np.zeros((bmax,), bool)
+        if isinstance(decision, VerifyBatch):
+            lanes = self._verify_lanes
+            token = np.zeros((bmax, lanes), np.int32)
+            rlen = np.ones((bmax,), np.int32)
+            for seq, drft in zip(decision.seqs, decision.drafts):
+                token[seq.slot, 0] = seq.out_tokens[-1]
+                token[seq.slot, 1:1 + len(drft)] = drft
+                kvl[seq.slot] = seq.kv_len - 1  # context written
+                rlen[seq.slot] = 1 + len(drft)
+                active[seq.slot] = True
+            return self._verify_fn, (
+                self.params, token, self.cache, self.kv.page_table_array(),
+                kvl, rlen, active)
+        assert isinstance(decision, DecodeBatch)
+        token = np.zeros((bmax,), np.int32)
+        for seq in decision.seqs:
+            token[seq.slot] = seq.out_tokens[-1]
+            kvl[seq.slot] = seq.kv_len - 1  # context written
+            active[seq.slot] = True
+        return self._decode_fn, (
+            self.params, self._put_tok(token), self.cache,
+            self.kv.page_table_array(), kvl, active)
+
+    def _land(self, decision, ids, logits) -> None:
+        """Fetch what the host needs of an executed step's outputs and
+        hand the scheduler its tokens."""
+        if isinstance(decision, PrefillChunk):
+            seq = decision.seq
+            self.sched.completed_prefill(decision)
+            if not seq.prefilling:  # prompt done -> first token
+                # mid-prompt chunks fetch NOTHING (pure dispatch); the
+                # final chunk fetches [1] int32 — or the logits row on
+                # the fallback path
+                if self.ecfg.device_sample:
+                    tok = int(self._fetch(ids)[0])
+                else:
+                    tok = self._sample(self._fetch(logits[0]))
+                self.sched.append_token(seq, tok)
+        elif isinstance(decision, VerifyBatch):
+            if self.ecfg.device_sample:
+                argmax_all = self._fetch(ids)     # [B, K+1] int32
+            else:
+                # logits fallback: one batched argmax over the whole
+                # [B, K+1, V] block (the former per-lane Python loop,
+                # vectorized — same first-occurrence tie-breaking)
+                argmax_all = np.argmax(self._fetch(logits), axis=-1)
+            results = []
+            for seq, drft in zip(decision.seqs, decision.drafts):
+                # lane i's logits predict the token after lane i; lanes
+                # past real_len are padding — never consulted
+                argmax = [int(t) for t in
+                          argmax_all[seq.slot, :1 + len(drft)]]
+                n_acc, emitted = draft_mod.accept_drafts(drft, argmax)
+                eos = seq.req.eos_id
+                if eos is not None and eos in emitted:
+                    # tokens after eos were never really generated; if
+                    # the cut drops the bonus token, every emitted token
+                    # is an accepted draft
+                    emitted = emitted[:emitted.index(eos) + 1]
+                    n_acc = min(n_acc, len(emitted))
+                results.append((n_acc, emitted))
+            # appends tokens, counts accept stats, truncates rejected-
+            # suffix pages (KV rollback, DESIGN.md §14)
+            self.sched.completed_verify(decision, results)
+        else:
+            if self.ecfg.device_sample:
+                toks = self._fetch(ids)           # [B] int32
+            else:
+                toks = np.argmax(self._fetch(logits), axis=-1)
+            for seq in decision.seqs:
+                self.sched.append_token(seq, int(toks[seq.slot]))
 
     def run(self, on_step=None) -> dict[int, Completion]:
         """Drive until every submitted request reaches a terminal status.

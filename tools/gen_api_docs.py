@@ -8,6 +8,7 @@ Covered modules (the surface README.md points users at):
 * ``repro.runtime.scheduler`` — continuous-batching scheduler + policies
 * ``repro.runtime.kv_cache``  — paged-KV accounting + radix prefix cache
 * ``repro.runtime.draft``     — speculative-decode draft sources + acceptance
+* ``repro.runtime.spans``     — the engine's host trace spans + step names
 * ``repro.core.precision``    — precision recipes (int8/fp8/w4 pipelines)
 * ``repro.core.compressed``   — packed (2N-2):2N operand + (de)compression
 * ``repro.kernels.roofline``  — analytic bytes/flops cost model + peaks
@@ -42,6 +43,7 @@ MODULES = [
     "repro.runtime.scheduler",
     "repro.runtime.kv_cache",
     "repro.runtime.draft",
+    "repro.runtime.spans",
     "repro.runtime.faults",
     "repro.core.precision",
     "repro.core.compressed",
