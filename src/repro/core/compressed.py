@@ -9,7 +9,11 @@ TPU kernel can decode with elementwise operations only:
   window ``j`` for every (group, output row); pad = 0
 * ``indices`` [..., P, G, out] — int8 in-window positions (0..N-1)
 
-with ``P = w*M`` slots per L-group and ``G = K/L`` groups.  Each plane is a
+with ``P = w*M`` slots per L-group and ``G = K/L`` groups.  Inside a
+scanned stack of units the operand may carry the whole ``[U, P, G, out]``
+stacks plus a traced unit index ``layer``: the kernel then reads that
+unit's tiles in place, and :meth:`CompressedSlided.unstacked` is the one
+unit's operand for every other consumer.  Each plane is a
 2-D ``[G, out]`` array, so decompression is a compare-and-select per plane
 (``kernels.slide_matmul``) rather than a reshape of the lane dimension, and
 ``out`` is the minor dim, which is what the TPU's default layout keeps
@@ -55,14 +59,26 @@ class CompressedSlided:
     m: int
     n: int
     packed: bool = False  # True: values nibble-packed (int4 'w4' recipe)
+    # set: values/indices are [U, ...] stacks and this is the unit's index
+    layer: jax.Array | None = None
 
     def tree_flatten(self):
-        return ((self.values, self.indices),
+        return ((self.values, self.indices, self.layer),
                 (self.k, self.z, self.l, self.m, self.n, self.packed))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
+        values, indices, layer = children
+        return cls(values, indices, *aux, layer=layer)
+
+    def unstacked(self) -> "CompressedSlided":
+        """The operand of unit ``layer`` alone (a copy of its slice of the
+        stacks); ``self`` when the operand is not a stack."""
+        if self.layer is None:
+            return self
+        return dataclasses.replace(self, values=self.values[self.layer],
+                                   indices=self.indices[self.layer],
+                                   layer=None)
 
     @property
     def decomposition(self) -> SlideDecomposition:
@@ -205,7 +221,7 @@ def split_out(c: CompressedSlided, shards: int) -> list[CompressedSlided]:
     return [CompressedSlided(
         c.values[..., i * step:(i + 1) * step],
         c.indices[..., i * step:(i + 1) * step],
-        c.k, c.z, c.l, c.m, c.n, c.packed) for i in range(shards)]
+        c.k, c.z, c.l, c.m, c.n, c.packed, c.layer) for i in range(shards)]
 
 
 def split_k(c: CompressedSlided, shards: int) -> list[CompressedSlided]:
@@ -230,7 +246,8 @@ def split_k(c: CompressedSlided, shards: int) -> list[CompressedSlided]:
     return [CompressedSlided(
         c.values[..., i * g_step:(i + 1) * g_step, :],
         c.indices[..., i * g_step:(i + 1) * g_step, :],
-        c.k // shards, c.z, c.l, c.m, c.n, c.packed) for i in range(shards)]
+        c.k // shards, c.z, c.l, c.m, c.n, c.packed, c.layer)
+        for i in range(shards)]
 
 
 def pack_meta(indices: jax.Array) -> jax.Array:

@@ -192,10 +192,12 @@ def apply(params: dict[str, Any], x: jax.Array, cfg: SparsityConfig,
 
     if cfg.mode == "compressed":
         k = params["indices"].shape[-2] * dec.source.l  # [P, K/L, out]
+        # "layer": values/indices are a scanned unit stack, read in place
+        # at that unit (models.transformer's unit scan)
         c = comp.CompressedSlided(
             params["values"], params["indices"], k,
             dec.source.z, dec.source.l, dec.hw.m, dec.hw.n,
-            packed=rec.packed_weights)
+            packed=rec.packed_weights, layer=params.get("layer"))
         return done(kops.compressed_matmul(
             x, c, s_w=params.get("s_w"), recipe=rec,
             out_dtype=out_dtype, use_pallas=cfg.use_pallas,

@@ -51,16 +51,13 @@ def apply_activation(v: jax.Array, activation: str | None) -> jax.Array:
     return ACTIVATIONS[activation](v)
 
 
-def prepare_bias(bias, m: int, pad_m: int):
-    """Shared bias-operand prep for the GEMM kernels: (has_bias, [1, m+pad]
+def prepare_bias(bias, m: int):
+    """Shared bias-operand prep for the GEMM kernels: (has_bias, [1, m]
     fp32).  A zeros row stands in when there is no bias — the kernels
     specialize on the static has_bias flag and skip the add."""
     has_bias = bias is not None
     b = (bias if has_bias else jnp.zeros((m,), jnp.float32))
-    b = b.astype(jnp.float32).reshape(1, m)
-    if pad_m:
-        b = jnp.pad(b, ((0, 0), (0, pad_m)))
-    return has_bias, b
+    return has_bias, b.astype(jnp.float32).reshape(1, m)
 
 
 def clamp_rows(br: int, rows: int) -> int:
@@ -163,12 +160,13 @@ def fused_slided_matmul_pallas(x, w_slided_q, s_w, bias=None, *, n_fam: int,
     br = clamp_rows(br, rows)
 
     pad_r, pad_m = (-rows) % br, (-m) % bm
-    has_bias, b = prepare_bias(bias, m, pad_m)
+    has_bias, b = prepare_bias(bias, m)
     if pad_r:
         x = jnp.pad(x, ((0, pad_r), (0, 0)))
     if pad_m:
         w_slided_q = jnp.pad(w_slided_q, ((0, pad_m), (0, 0)))
         s_w = jnp.pad(s_w, ((0, pad_m), (0, 0)), constant_values=1.0)
+        b = jnp.pad(b, ((0, 0), (0, pad_m)))
     rp, mp = x.shape[0], w_slided_q.shape[0]
 
     grid = (rp // br, mp // bm)

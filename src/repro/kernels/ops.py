@@ -132,7 +132,8 @@ def compressed_matmul(x: jax.Array, c: CompressedSlided,
     PrecisionRecipe) require rowwise-quantized compressed values + s_w row
     scales and perform the fused per-token quantization on x; ``c.packed``
     must match the recipe's weight storage.  ``act_quant`` is the legacy
-    spelling and maps onto the equivalent recipe.
+    spelling and maps onto the equivalent recipe.  ``c.layer`` set: ``c``
+    is a scanned unit stack, computed at that unit.
     """
     rec = precision.resolve(recipe, act_quant)
     out_dtype = out_dtype or rec.out_dtype(x.dtype)

@@ -59,7 +59,7 @@ def quant_matmul_pallas(q_x, q_w, s_x, s_w, bias=None, *,
     m = q_w.shape[0]
     br = clamp_rows(br, rows)
     pad_r, pad_k, pad_m = (-rows) % br, (-k) % bk, (-m) % bm
-    has_bias, b = prepare_bias(bias, m, pad_m)
+    has_bias, b = prepare_bias(bias, m)
     if pad_r or pad_k:
         q_x = jnp.pad(q_x, ((0, pad_r), (0, pad_k)))
     if pad_r:
@@ -68,6 +68,7 @@ def quant_matmul_pallas(q_x, q_w, s_x, s_w, bias=None, *,
         q_w = jnp.pad(q_w, ((0, pad_m), (0, pad_k)))
     if pad_m:
         s_w = jnp.pad(s_w, ((0, pad_m), (0, 0)), constant_values=1.0)
+        b = jnp.pad(b, ((0, 0), (0, pad_m)))
     rp, kp, mp = q_x.shape[0], q_x.shape[1], q_w.shape[0]
     k_steps = kp // bk
     grid = (rp // br, mp // bm, k_steps)

@@ -70,10 +70,11 @@ def compressed_matmul_fp(x: jax.Array, c: comp.CompressedSlided,
     """Float path: decompress-to-original-layout weights, dense matmul.
 
     x: [rows, K]; returns [rows, out].  The TPU-adapted execution of
-    DESIGN.md §2 — 1.0x dense FLOPs, compressed weight storage.
+    DESIGN.md §2 — 1.0x dense FLOPs, compressed weight storage.  A stacked
+    operand (``c.layer`` set) is indexed at its unit first.
     """
     out_dtype = out_dtype or x.dtype
-    w_rec = comp.decompress_original(c)  # [out, K]
+    w_rec = comp.decompress_original(c.unstacked())  # [out, K]
     acc = jax.lax.dot_general(
         x.astype(jnp.float32), w_rec.astype(jnp.float32),
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -98,7 +99,7 @@ def compressed_matmul_quant(x: jax.Array, c: comp.CompressedSlided,
     rec = precision.resolve(recipe)
     out_dtype = out_dtype or x.dtype
     qx = rec.quantize_act(x, absmax=act_absmax)
-    w_rec = comp.decompress_original(c)  # int8-range [out, K]
+    w_rec = comp.decompress_original(c.unstacked())  # int8-range [out, K]
     acc = _quant_dot(qx.q, w_rec)
     y = acc.astype(jnp.float32) * qx.scale * s_w[:, 0][None, :]
     return epilogue(y, bias, activation).astype(out_dtype)

@@ -30,6 +30,16 @@ cached dense tile.  Total decompressions per call are ``(M/bm) *
 ceil(K/bk)`` regardless of R.  The dequant epilogue optionally fuses a
 bias add and SiLU/GELU so the transformer MLP gate/up projections need no
 separate elementwise pass.
+
+Inside a scanned stack of units the weight operand is the whole ``[U, P,
+G, M]`` stack plus a scalar-prefetched unit index: the weight BlockSpecs
+DMA that unit's tiles straight from the stack, so XLA does not copy each
+unit's slice into a fresh buffer before the call (it cannot fuse a slice
+into a custom call's operand).  No weight operand is padded, which would
+copy it every call: where no lane-legal tile divides M, the last output
+block runs past M (Pallas reads unspecified values there and drops those
+output columns on write; column m of the output reads column m of the
+weights alone).
 """
 from __future__ import annotations
 
@@ -178,9 +188,9 @@ def choose_bk(l: int, groups: int = 32) -> int:
 
 
 def _bm_candidates(m: int, target: int = 512) -> list[int]:
-    """Lane-legal output tiles, best first: multiples of 128 that divide M
-    (no per-call padding), else M itself; then padded multiples of 128 as
-    the fallback when those do not fit VMEM."""
+    """Lane-legal output tiles, best first: multiples of 128 that divide M,
+    else M itself; then multiples of 128 with a partial last output block,
+    for when those do not fit VMEM."""
     exact = [b for b in range(min(target, m) // 128 * 128, 0, -128)
              if m % b == 0] or [m]
     return exact + [b for b in (512, 256, 128) if b < m and b not in exact]
@@ -227,7 +237,8 @@ def default_tiles(m: int, k: int, kc: int, x_itemsize: int,
     jax.jit,
     static_argnames=("n_fam", "quantized", "interpret", "bm", "br", "bk",
                      "out_dtype", "activation", "instrument"))
-def compressed_matmul_pallas(x, values, indices, s_x, s_w, bias=None, *,
+def compressed_matmul_pallas(x, values, indices, s_x, s_w, bias=None,
+                             layer=None, *,
                              n_fam: int, quantized: bool,
                              out_dtype=jnp.float32, interpret: bool = False,
                              bm: int | None = None, br: int | None = None,
@@ -240,6 +251,10 @@ def compressed_matmul_pallas(x, values, indices, s_x, s_w, bias=None, *,
     ``values``/``indices``: slot-planar ``[P, K/L, M]`` operands
     (``core.compressed``); values with ``P/2`` planes are nibble-packed
     int4 pairs (the 'w4' recipe), sign-extended in the decompress prologue.
+    With ``layer`` (an int32 scalar, may be traced) they are ``[U, P, K/L,
+    M]`` stacks and the call reads unit ``layer`` in place; tiles are
+    chosen from the per-unit shape.  A width that ``bm`` does not divide
+    is read with a partial last output block, never padded.
     quantized=True: x int8 or float8_e4m3fn, integer values; int32
     accumulate for all-integer operands, fp32 (lossless casts) when any
     operand is fp8; epilogue * s_x * s_w.
@@ -257,57 +272,69 @@ def compressed_matmul_pallas(x, values, indices, s_x, s_w, bias=None, *,
     if bk % l:
         raise ValueError(f"bk={bk} must be a multiple of L={l} so chunk "
                          "boundaries align with window groups")
-    kc = indices.shape[0] * g
-    w_item = values.dtype.itemsize * values.shape[0] / indices.shape[0]
+    kc = indices.shape[-3] * g
+    w_item = values.dtype.itemsize * values.shape[-3] / indices.shape[-3]
     x_fp8 = x.dtype == jnp.float8_e4m3fn
     dbm, dbr = default_tiles(m, k, kc, x.dtype.itemsize, w_item, x_fp8=x_fp8)
     bm, br = bm or dbm, br or dbr
     br = clamp_rows(br, rows)
 
-    pad_r, pad_m = (-rows) % br, (-m) % bm
-    has_bias, b = prepare_bias(bias, m, pad_m)
+    pad_r = (-rows) % br
+    has_bias, b = prepare_bias(bias, m)
     # offset-major activations: xp[o, r, g] = x[r, g*L + o]
     xp = x.reshape(rows, g, l).transpose(2, 0, 1)
     if pad_r:
         xp = jnp.pad(xp, ((0, 0), (0, pad_r), (0, 0)))
         s_x = jnp.pad(s_x, ((0, pad_r), (0, 0)), constant_values=1.0)
     s_w = s_w.reshape(1, m)
-    if pad_m:
-        # no lane-legal tile divides M: pad the weight operand per call
-        pm = ((0, 0), (0, 0), (0, pad_m))
-        values, indices = jnp.pad(values, pm), jnp.pad(indices, pm)
-        s_w = jnp.pad(s_w, ((0, 0), (0, pad_m)), constant_values=1.0)
 
-    rp, mp = xp.shape[1], values.shape[-1]
-    pv = values.shape[0]
+    rp = xp.shape[1]
+    pv, pi = values.shape[-3], indices.shape[-3]
     acc_dtype = (jnp.int32 if quantized and x.dtype == jnp.int8
                  else jnp.float32)
-    wdt = jnp.int8 if pv != indices.shape[0] else values.dtype
+    wdt = jnp.int8 if pv != pi else values.dtype
     need = tile_need(bm, br, k, kc, x.dtype.itemsize, w_item, x_fp8, l)
-
-    y = pl.pallas_call(
-        functools.partial(_mm_kernel, n_fam=n_fam, gc=bk // l,
-                          acc_dtype=acc_dtype, quantized=quantized,
-                          has_bias=has_bias, activation=activation,
-                          instrument=instrument),
-        grid=(mp // bm, rp // br),  # R innermost: decompress once per m
+    body = functools.partial(_mm_kernel, n_fam=n_fam, gc=bk // l,
+                             acc_dtype=acc_dtype, quantized=quantized,
+                             has_bias=has_bias, activation=activation,
+                             instrument=instrument)
+    # index maps take (m, r) and, with a stack, the prefetched unit index
+    if layer is None:
+        kernel, prefetch = body, ()
+        w_specs = [pl.BlockSpec((p, g, bm), lambda m_, r: (0, 0, m_))
+                   for p in (pv, pi)]
+    else:
+        def kernel(li_ref, *refs):  # only the index maps read the unit
+            body(*refs)
+        prefetch = (jnp.reshape(layer, (1,)).astype(jnp.int32),)
+        # the unit axis is squeezed: the kernel sees [P, g, bm] tiles
+        w_specs = [pl.BlockSpec((None, p, g, bm),
+                                lambda m_, r, li: (li[0], 0, 0, m_))
+                   for p in (pv, pi)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        # R innermost: decompress once per m
+        grid=(pl.cdiv(m, bm), rp // br),
         in_specs=[
-            pl.BlockSpec((l, br, g), lambda m_, r: (0, r, 0)),
-            pl.BlockSpec((pv, g, bm), lambda m_, r: (0, 0, m_)),
-            pl.BlockSpec((indices.shape[0], g, bm), lambda m_, r: (0, 0, m_)),
-            pl.BlockSpec((br, 1), lambda m_, r: (r, 0)),
-            pl.BlockSpec((1, bm), lambda m_, r: (0, m_)),
-            pl.BlockSpec((1, bm), lambda m_, r: (0, m_)),
+            pl.BlockSpec((l, br, g), lambda m_, r, *_: (0, r, 0)),
+            *w_specs,
+            pl.BlockSpec((br, 1), lambda m_, r, *_: (r, 0)),
+            pl.BlockSpec((1, bm), lambda m_, r, *_: (0, m_)),
+            pl.BlockSpec((1, bm), lambda m_, r, *_: (0, m_)),
         ],
-        out_specs=pl.BlockSpec((br, bm), lambda m_, r: (r, m_)),
-        out_shape=jax.ShapeDtypeStruct((rp, mp), out_dtype),
+        out_specs=pl.BlockSpec((br, bm), lambda m_, r, *_: (r, m_)),
         scratch_shapes=[pltpu.VMEM((l, g, bm), wdt)],
+    )
+    y = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rp, m), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(need)),
         interpret=interpret,
-    )(xp, values, indices, s_x, s_w, b)
-    return y[:rows, :m]
+    )(*prefetch, xp, values, indices, s_x, s_w, b)
+    return y[:rows]
 
 
 def compressed_matmul(x: jax.Array, c: CompressedSlided,
@@ -331,6 +358,6 @@ def compressed_matmul(x: jax.Array, c: CompressedSlided,
     if s_w is None:
         s_w = jnp.ones((mout, 1), jnp.float32)
     return compressed_matmul_pallas(
-        x, c.values, c.indices, s_x, s_w, bias, n_fam=n, quantized=quantized,
-        out_dtype=out_dtype, interpret=interpret, activation=activation,
-        **tiles)
+        x, c.values, c.indices, s_x, s_w, bias, c.layer, n_fam=n,
+        quantized=quantized, out_dtype=out_dtype, interpret=interpret,
+        activation=activation, **tiles)

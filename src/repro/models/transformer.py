@@ -337,6 +337,54 @@ def prefill(params, cfg: ModelConfig, tokens, max_len: int | None = None,
     return logits, cache, kv_len
 
 
+# ------------------------------------------------------ serving scans
+_STACKED = ("values", "indices")
+
+
+def _split_stacks(node):
+    """Unit params -> (stacks, rest) in the same dict structure: ``stacks``
+    holds the values/indices of every compressed linear, ``rest`` every
+    other leaf (None where a side has nothing)."""
+    if not isinstance(node, dict):
+        return None, node
+    if all(k in node for k in _STACKED):
+        return ({k: node[k] for k in _STACKED},
+                {k: v for k, v in node.items() if k not in _STACKED})
+    parts = {k: _split_stacks(v) for k, v in node.items()}
+    return ({k: st for k, (st, _) in parts.items()},
+            {k: r for k, (_, r) in parts.items()})
+
+
+def _join_stacks(stacks, rest, layer):
+    """Inverse of :func:`_split_stacks` for one unit: each compressed
+    linear gets the whole stacks and the unit index ``layer``."""
+    if stacks is None:
+        return rest
+    if all(k in stacks for k in _STACKED):
+        return {**rest, **stacks, "layer": layer}
+    return {k: _join_stacks(stacks[k], rest[k], layer) for k in rest}
+
+
+def _scan_units(unit_fn, x, units, cache):
+    """``jax.lax.scan(unit_fn, x, (units, cache))`` for the serving steps,
+    with every compressed linear's values/indices kept out of the scanned
+    inputs: they are loop invariants, and each unit's linear reaches the
+    kernel as the whole ``[U, ...]`` stacks plus the unit index, which it
+    reads in place (``kernels.slide_matmul``).  A scanned input is sliced
+    per unit, and XLA copies a slice into a fresh buffer before a custom
+    call.  Norms, scales and biases are small and stay scanned."""
+    stacks, rest = _split_stacks(units)
+    n = jax.tree_util.tree_leaves(units)[0].shape[0]
+
+    def body(carry, xs):
+        layer, unit_rest, unit_cache = xs
+        return unit_fn(carry, (_join_stacks(stacks, unit_rest, layer),
+                               unit_cache))
+
+    return jax.lax.scan(body, x, (jnp.arange(n, dtype=jnp.int32), rest,
+                                  cache))
+
+
 # ------------------------------------------------------- paged inference
 # All three paged step shapes (prefill chunk, decode, verify) attend
 # through attention.pool_attend, which dispatches between the KV-gather
@@ -425,7 +473,7 @@ def paged_prefill_chunk(params, cfg: ModelConfig, tokens, cache, page_table,
             new_cache[f"layer_{i}"] = nc
         return xx, new_cache
 
-    x, new_cache = jax.lax.scan(unit_fn, x, (params["units"], cache))
+    x, new_cache = _scan_units(unit_fn, x, params["units"], cache)
     h = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     last = jnp.clip(real_len - 1, 0, c - 1)
     h_last = jax.lax.dynamic_slice_in_dim(h, last, 1, axis=1)
@@ -474,7 +522,7 @@ def paged_decode_step(params, cfg: ModelConfig, token, cache, page_table,
             new_cache[f"layer_{i}"] = nc
         return xx, new_cache
 
-    x, new_cache = jax.lax.scan(unit_fn, x, (params["units"], cache))
+    x, new_cache = _scan_units(unit_fn, x, params["units"], cache)
     h = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, cfg, h)[:, 0]
     return logits, new_cache
@@ -520,7 +568,7 @@ def paged_verify_step(params, cfg: ModelConfig, tokens, cache, page_table,
             new_cache[f"layer_{i}"] = nc
         return xx, new_cache
 
-    x, new_cache = jax.lax.scan(unit_fn, x, (params["units"], cache))
+    x, new_cache = _scan_units(unit_fn, x, params["units"], cache)
     h = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, cfg, h)
     return logits, new_cache
@@ -540,7 +588,7 @@ def serve_step(params, cfg: ModelConfig, token, cache, kv_len):
                                      cache=unit_cache, kv_len=kv_len)
         return out, new_cache
 
-    x, new_cache = jax.lax.scan(unit_fn, x, (params["units"], cache))
+    x, new_cache = _scan_units(unit_fn, x, params["units"], cache)
     h = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_fn(params, cfg, h)[:, 0]
     return logits, new_cache, kv_len + 1

@@ -11,7 +11,9 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library at a time,
 and every test worker imports every test file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,8 +61,13 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("rows", [8, PREFILL_ROWS])
 @pytest.mark.parametrize("name,m,k", [("w_up", D_FF, D_MODEL),
                                       ("w_down", D_MODEL, D_FF),
-                                      ("wq", HEADS * HEAD_DIM, D_MODEL)])
+                                      ("wq", HEADS * HEAD_DIM, D_MODEL),
+                                      ("wk", KV_HEADS * HEAD_DIM, D_MODEL)])
 def test_compressed_matmul_compiles(one_chip, name, m, k, rows, recipe):
+    """The weights reach the kernel as stored: no pad or copy of a
+    ``[P, G, *]`` weight array, also at wk's 960, a width no lane-legal
+    tile that fits divides (a partial last output block).  XLA may still
+    prefetch a small operand whole into VMEM (slices of the same width)."""
     pat = Pattern(Z, L)
     planes, groups = pat.family_n * 2 - 2, k // L
     wdt = jnp.int8 if recipe == "int8" else jnp.bfloat16
@@ -77,6 +84,11 @@ def test_compressed_matmul_compiles(one_chip, name, m, k, rows, recipe):
 
     compiled = _compile(gemm, *args)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    text = compiled.as_text()
+    made = [ln for ln in text[text.index("\nENTRY"):].splitlines()
+            if (w := re.search(rf"= \S+\[{planes},{groups},(\d+)\]", ln))
+            and (int(w[1]) != m or re.search(r" (pad|copy|copy-start)\(", ln))]
+    assert not made, made[:1]
 
 
 @pytest.mark.parametrize("recipe", ["fp8", "w4", "fp8w4"])
@@ -122,3 +134,54 @@ def test_paged_attention_compiles(one_chip, batch, lanes, kv_dtype):
                                   sliding_window=4096, use_pallas=True)
 
     _compile(attend, q, pool, table, kv_len)
+
+
+def _shapes(line: str) -> list[tuple[int, ...]]:
+    """Every array shape written in one HLO line, e.g. ``bf16[2,6,480]``."""
+    return [tuple(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", line)]
+
+
+@pytest.mark.parametrize("recipe", ["none", "int8"])
+def test_unit_scan_reads_weight_stacks_in_place(one_chip, recipe):
+    """A 2-unit decode step at danube widths: every compressed GEMM in the
+    unit scan takes the whole [2, 6, G, M] stacks, and no dynamic-slice
+    fusion copies one unit's [6, G, M] weight slice out of them."""
+    from repro.configs import registry
+    from repro.core.linear import SparsityConfig
+    from repro.models import model as M
+    from repro.runtime.serve_loop import pack_params
+
+    units, page_size, batch = 2, 16, 8
+    cfg = dataclasses.replace(
+        registry.get("h2o-danube-3-4b"), num_layers=units,
+        sparsity=SparsityConfig(pattern=(Z, L), mode="compressed",
+                                recipe=recipe, use_pallas=True))
+    params = jax.eval_shape(lambda k: pack_params(M.init(cfg, k), cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: M.make_paged_cache(cfg, 64, page_size,
+                                                      batch))
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+    stacks = {a.shape for a in jax.tree_util.tree_leaves(params["units"])
+              if a.ndim == 4}
+    assert len(stacks) == 4 and all(s[:2] == (units, 6) for s in stacks)
+    args = (place(params), _spec((batch,), jnp.int32, one_chip),
+            place(cache), _spec((batch, 8), jnp.int32, one_chip),
+            _spec((batch,), jnp.int32, one_chip),
+            _spec((batch,), jnp.bool_, one_chip))
+    hlo = _compile(lambda p, t, c, pt, kl, act: M.paged_decode_step(
+        p, cfg, t, c, pt, kl, act, page_size), *args).as_text()
+
+    slices = [ln for ln in hlo.splitlines()
+              if re.match(r"\s*%\S*dynamic-slice\S*fusion\S* = ", ln)]
+    unit_slices = {s[1:] for s in stacks}
+    copied = [ln for ln in slices if _shapes(ln.split(" = ", 1)[1])[:1]
+              and _shapes(ln.split(" = ", 1)[1])[0] in unit_slices]
+    assert not copied, copied[:2]
+
+    calls = [ln for ln in hlo.splitlines()
+             if re.match(r"\s*%compressed_matmul_pallas\S* = ", ln)]
+    stacked = [ln for ln in calls if stacks & set(_shapes(ln))]
+    # every call but the LM head's (outside the scan) reads the stacks
+    assert len(stacked) >= 7 and len(calls) - len(stacked) == 1
