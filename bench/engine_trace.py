@@ -256,7 +256,7 @@ def main(argv=None) -> int:
            "span_inactive_us": span_cost_us()}
     cfg = model.program_config(cell.config)
     engine = model.make_engine(model.init_params(cfg, args.seed), cfg,
-                               cell.traffic["engine"])
+                               cell.traffic["engine"], cell.chips)
     engine.warmup()
     plan = traffic.Plan(cell.traffic, cell.config["vocab_size"], args.seed)
     tmp = tempfile.mkdtemp(prefix="engine_trace_") if args.trace else None

@@ -1,13 +1,16 @@
-"""The plain reference: a float32 forward of the 6:8-pruned model, built
-from the configuration file and the seed alone.
+"""The plain reference: a float32 forward of the model a configuration
+file describes, pruned as its ``sparsity`` says, built from the file and
+the seed alone.
 
 It imports nothing of the program and takes nothing the program made.
 Its weights are drawn from the seed by the same random draws the
 program's initialisation makes (so both sides hold the same model), then
-pruned here: in every group of 8 along the input dimension the 6 largest
-magnitudes stay, ties going to the earlier position.  Every matrix
-product runs at ``HIGHEST`` precision, layer by layer, so that it fits
-one chip beside nothing else.
+pruned here: in every group of ``l`` along the input dimension the ``z``
+largest magnitudes stay, ties going to the earlier position (a
+``pattern`` of ``null`` keeps every weight).  Every matrix product runs
+at ``HIGHEST`` precision, layer by layer, so that it fits one chip beside
+nothing else.  The layers are the configuration's family's
+(``bench/families/<family>.py``); the pieces here are shared by all.
 
 ``quant`` computes the same forward in a lower precision, for the
 control of the comparison that decides ``correct``: ``"int8"`` quantizes
@@ -22,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import cells
 from bench.seeds import model_key
 
 HI = jax.lax.Precision.HIGHEST
@@ -88,8 +92,8 @@ def _quant(x, qmax):
 
 
 def _prep(w, c, quant):
-    z, l = c["sparsity"]["pattern"]
-    w = prune(w, z, l)
+    pattern = c["sparsity"]["pattern"]
+    w = prune(w, *pattern) if pattern else w.astype(jnp.float32)
     return w if quant is None else _quant(w, QMAX[quant][0])
 
 
@@ -132,21 +136,6 @@ def _attend(q, k, v, window):
     return out.reshape(s, h, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "quant"))
-def _layer(x, w, shape, quant):
-    h_, kvh, hd, theta, window, eps = shape
-    n, s, _ = x.shape
-    hn = _rms(x, eps)
-    q = _rope(_lin(hn, w["wq"], quant).reshape(n, s, h_, hd), theta)
-    k = _rope(_lin(hn, w["wk"], quant).reshape(n, s, kvh, hd), theta)
-    v = _lin(hn, w["wv"], quant).reshape(n, s, kvh, hd)
-    a = jax.lax.map(lambda qkv: _attend(*qkv, window), (q, k, v))
-    x = x + _lin(a.reshape(n, s, h_ * hd), w["wo"], quant)
-    hn = _rms(x, eps)
-    g, u = _lin(hn, w["w_gate"], quant), _lin(hn, w["w_up"], quant)
-    return x + _lin(jax.nn.silu(g) * u, w["w_down"], quant)
-
-
 @functools.partial(jax.jit, static_argnames=("eps", "quant"))
 def _head(x, w, cands, eps, quant):
     def one(args):
@@ -166,18 +155,4 @@ def forward(c: dict, seed: int, tokens: np.ndarray, cands: np.ndarray,
     positions before it).  Returns the gaps ``[N, S, K]`` by which the
     logits of ``cands[n, p, :]`` lie below the best logit at position
     ``p``, and the argmax ``[N, S]``."""
-    ke, kh, unit_keys = model_keys(c, seed)
-    window = c.get("sliding_window") or tokens.shape[1]
-    shape = (c["num_attention_heads"], c["num_key_value_heads"],
-             c["head_dim"], float(c["rope_theta"]), int(window),
-             float(c["rms_norm_eps"]))
-    x = jnp.take(embedding(c, ke), jnp.asarray(tokens), axis=0
-                 ).astype(jnp.float32)
-    for u in range(c["num_hidden_layers"]):
-        w = {k: _prep(v, c, quant)
-             for k, v in layer_weights(c, unit_keys[u]).items()}
-        x = _layer(x, w, shape, quant)
-        del w
-    gap, top = _head(x, _prep(head_weights(c, kh), c, quant),
-                     jnp.asarray(cands), float(c["rms_norm_eps"]), quant)
-    return np.asarray(gap), np.asarray(top)
+    return cells.family(c).forward(c, seed, tokens, cands, quant)

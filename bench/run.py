@@ -84,7 +84,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     params = model.init_params(cfg, seed)
     setup["init_pack_s"] = time.time() - t
     t = time.time()
-    engine = model.make_engine(params, cfg, cell.traffic["engine"])
+    engine = model.make_engine(params, cfg, cell.traffic["engine"],
+                               cell.chips)
     engine.warmup()
     setup["warmup_s"] = time.time() - t
     plan = traffic.Plan(cell.traffic, cell.config["vocab_size"], seed)

@@ -38,7 +38,8 @@ def main(argv=None) -> int:
     compiles = driver.Compiles()
     for rate in (float(r) for r in args.rates.split(",")):
         # a fresh engine per rate: request ids restart with each plan
-        engine = model.make_engine(params, cfg, cell.traffic["engine"])
+        engine = model.make_engine(params, cfg, cell.traffic["engine"],
+                                   cell.chips)
         engine.warmup()
         queue: list[tuple[float, int]] = []
 

@@ -3,6 +3,7 @@ cell and metric can be added from files alone."""
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -42,17 +43,66 @@ def test_benchmark_json_shape():
             assert w in e2e[m["moves"]].get("workloads", [w])
 
 
+def _maps(c: dict):
+    """What every family's configuration file maps to; its family's
+    ``check`` says how the rest of its keys do."""
+    cfg = model.program_config(c)
+    assert cfg.d_model == c["hidden_size"]
+    assert cfg.vocab_size == c["vocab_size"]
+    assert cfg.sparsity.mode == (
+        "compressed" if c["sparsity"]["pattern"] else "dense")
+    assert cfg.sparsity.recipe.name == c["sparsity"]["recipe"]
+    cells.family(c).check(c, cfg)
+    assert set(c["reduced"]) <= set(c.get("published", {}))
+
+
+def _loads(c: cells.Cell, bench_dir: str = cells.BENCH):
+    engine = c.traffic["engine"]
+    assert c.chips in (1, 4) and c.end_to_end and c.per_layer
+    assert model.engine_config(engine, c.chips).tp == engine.get("tp", 1)
+    _maps(c.config)
+    for m in c.end_to_end + c.per_layer:
+        assert callable(cells.reader(m["name"], bench_dir))
+
+
+def _bench_dir(tmp_path):
+    """A bench directory as a checkout has it, without configurations
+    and mixes."""
+    bench = tmp_path / "bench"
+    for d in ("configs", "traffic"):
+        (bench / d).mkdir(parents=True)
+    for d in ("families", "metrics"):
+        shutil.copytree(os.path.join(cells.BENCH, d), bench / d)
+    return bench
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
 def test_every_cell_loads_by_name(cell):
-    c = cells.load_cell(cell)
-    assert c.chips == 1 and c.end_to_end and c.per_layer
-    cfg = model.program_config(c.config)
-    assert cfg.d_model == c.config["hidden_size"]
-    assert cfg.num_layers == c.config["num_hidden_layers"]
-    assert cfg.sparsity.mode == "compressed"
-    assert cfg.sparsity.recipe.name == c.config["sparsity"]["recipe"]
-    for m in c.end_to_end + c.per_layer:
-        assert callable(cells.reader(m["name"]))
+    _loads(cells.load_cell(cell))
+
+
+def test_a_four_chip_tp_cell_from_files_alone(tmp_path):
+    """A cell that shards its model over four chips is a mix whose
+    ``engine`` sets ``tp`` and a cell entry with ``chips`` 4."""
+    bench = _bench_dir(tmp_path)
+    shutil.copy(os.path.join(cells.BENCH, "configs", "danube4b-68-bf16.json"),
+                bench / "configs")
+    mix = cells.load_traffic("decode_b16")
+    mix = dict(mix, engine=dict(mix["engine"], tp=4))
+    (bench / "traffic" / "decode_tp4.json").write_text(json.dumps(mix))
+    every = {k: [{f: v for f, v in m.items() if f != "workloads"}
+                 for m in SPEC[k]] for k in ("end_to_end", "per_layer")}
+    spec = dict(SPEC, **every, configs=[
+        c for c in SPEC["configs"] if c["name"] == "danube4b-68-bf16"],
+        workloads=[{"name": "danube4b-68-bf16.tp4",
+                    "config": "danube4b-68-bf16", "traffic": "decode_tp4",
+                    "chips": 4, "why": "fixture"}])
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(spec))
+    cell = cells.load_cell("danube4b-68-bf16.tp4", str(path), str(bench))
+    _loads(cell, str(bench))
+    assert cell.chips == 4
+    assert model.engine_config(cell.traffic["engine"], cell.chips).tp == 4
 
 
 def test_cell_config_and_metric_from_files_alone(tmp_path):
@@ -91,12 +141,26 @@ def test_cell_config_and_metric_from_files_alone(tmp_path):
 @pytest.mark.parametrize("name", sorted(
     f[:-5] for f in os.listdir(os.path.join(cells.BENCH, "configs"))))
 def test_every_configuration_file_maps_to_the_program(name):
-    c = cells.load_config(name)
+    _maps(cells.load_config(name))
+
+
+def test_a_full_attention_file_maps_to_the_program(tmp_path):
+    """A dense file with no ``sliding_window`` is the model with every
+    layer full: the program's units, and the yardstick's keys."""
+    from bench import yardstick
+
+    bench = _bench_dir(tmp_path)
+    conf = cells.load_json(os.path.join(cells.BENCH, "configs",
+                                        "danube4b-dense-bf16.json"))
+    del conf["sliding_window"]
+    conf["name"] = "full-attention"
+    (bench / "configs" / "full-attention.json").write_text(json.dumps(conf))
+    c = cells.load_config("full-attention", str(bench))
+    _maps(c)
     cfg = model.program_config(c)
-    assert (cfg.d_model, cfg.d_ff, cfg.num_heads, cfg.num_kv_heads,
-            cfg.resolved_head_dim, cfg.vocab_size) == (
-        c["hidden_size"], c["intermediate_size"], c["num_attention_heads"],
-        c["num_key_value_heads"], c["head_dim"], c["vocab_size"])
-    assert cfg.sliding_window == c["sliding_window"]
-    assert cfg.norm_eps == c["rms_norm_eps"]
-    assert set(c["reduced"]) <= set(c.get("published", {}))
+    assert cfg.unit_pattern == ("attn",)
+    with pytest.raises(ValueError, match="window 1024"):
+        cells.family(c).check(dict(c, sliding_window=1024), cfg)
+    swa = dict(c, sliding_window=4096)
+    assert yardstick.decode_work(c, [6000]).attn_ops > \
+        yardstick.decode_work(swa, [6000]).attn_ops

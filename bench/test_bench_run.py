@@ -28,6 +28,10 @@ E2E = tuple({"name": n, "unit": u} for n, u in (
 # every fault below reads far above both
 LIMIT = 0.045
 SEED = 2 ** 35 + 3
+# the same model served dense: sound runs 0.0026-0.0416 and the control
+# 0.066-0.166 on eleven seeds (0.0227 and 0.110 on SEED)
+TINY_DENSE = dict(TINY, sparsity={"pattern": None, "recipe": "none"})
+DENSE_LIMIT = 0.05
 
 
 @pytest.fixture(autouse=True)
@@ -35,10 +39,10 @@ def no_disk_cache(monkeypatch):
     monkeypatch.setattr(run, "enable_cache", lambda: "off")
 
 
-def tiny_run(seed=SEED, control=False):
-    cell = cells.Cell("tiny", TINY, MIX, 1, E2E, ())
+def tiny_run(seed=SEED, control=False, config=TINY, limit=LIMIT):
+    cell = cells.Cell("tiny", config, MIX, 1, E2E, ())
     return run.run_cell(cell, seed, 1.5, False, time.time(),
-                        peaks.for_kind("TPU v5 lite"), limit=LIMIT,
+                        peaks.for_kind("TPU v5 lite"), limit=limit,
                         control=control)
 
 
@@ -67,8 +71,8 @@ def _faulty(kind):
     """The engine with its decode step broken where the tokens are made."""
     make = model.make_engine
 
-    def make_engine(params, cfg, engine):
-        eng = make(params, cfg, engine)
+    def make_engine(params, cfg, engine, chips=1):
+        eng = make(params, cfg, engine, chips)
         orig = eng._decode_fn
 
         def step(p, tok, c, pt, kvl, act):
@@ -87,23 +91,32 @@ def _faulty(kind):
     return make_engine
 
 
+MODELS = pytest.mark.parametrize("config,limit", [
+    (TINY, LIMIT), (TINY_DENSE, DENSE_LIMIT)], ids=["compressed", "dense"])
+
+
+@MODELS
 @pytest.mark.parametrize("kind", ["state_unchanged", "half_batch",
                                   "token_altered"])
-def test_a_broken_timed_path_is_not_correct(kind, monkeypatch):
+def test_a_broken_timed_path_is_not_correct(kind, config, limit,
+                                            monkeypatch):
     monkeypatch.setattr(model, "make_engine", _faulty(kind))
-    out = tiny_run()
+    out = tiny_run(config=config, limit=limit)
     assert out["checks"]["compiles_in_window"]["value"] == 0
-    assert out["checks"]["max_logit_gap"]["value"] > LIMIT
+    assert out["checks"]["max_logit_gap"]["value"] > limit
     assert out["correct"] is False
 
 
-def test_the_control_is_not_correct():
+@MODELS
+def test_the_control_is_not_correct(config, limit):
     """The reference one precision below the configuration's, in the
-    program's place, reads above the limit that sound runs keep."""
-    out = tiny_run(control=True)
+    program's place (the unpruned reference at int8 for a dense
+    configuration), reads above the limit that sound runs keep."""
+    out = tiny_run(control=True, config=config, limit=limit)
     c = out["checks"]
-    assert c["max_logit_gap"]["value"] <= LIMIT
-    assert c["control_max_logit_gap"]["value"] > LIMIT
+    assert out["correct"] is True
+    assert c["max_logit_gap"]["value"] <= limit
+    assert c["control_max_logit_gap"]["value"] > limit
 
 
 @pytest.mark.parametrize("n", [4, 8])

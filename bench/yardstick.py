@@ -1,30 +1,35 @@
-"""The work a 6:8-sparse model requires of one serving step, counted
-from its configuration and the rows the step served — never from the
-program's stored format, so a change of format cannot move it.
+"""The work a model requires of one serving step, counted from its
+configuration and the rows the step served — never from the program's
+stored format, so a change of format cannot move it.
 
 - Weights: the nonzeros at the recipe's value width (bf16 2 B, int8
-  1 B, plus a float32 scale per output row for int8), and 5 bits of
-  position per group of 8, the least any 6:8 format can store
-  (C(8, 6) = 28 < 2**5).
+  1 B, plus a float32 scale per output row for int8), and for a z:l
+  pattern the least any z:l format can store of their positions,
+  ceil(log2 C(l, z)) bits per group (5 per 8 at 6:8: C(8, 6) = 28 <
+  2**5).  A dense model (``pattern`` ``null``) keeps every weight, with
+  no positions.
 - Other bytes: the activations into and out of every linear, and the
   K/V bytes each active lane's attention reads and writes.
 - Operations: 2 x nonzeros x the rows the step served (active lanes,
   not ``max_batch``), plus attention over each lane's visible context.
 
-The least time a step can take is the larger of its operations over the
-peak rate (the int8 rate for the GEMMs of an int8 recipe) and its bytes
-over the peak bandwidth.
+Which linears and layers a step runs is the configuration's family's
+(``bench/families/<family>.py``, its ``step_work``); the counts here
+are shared by all.  The least time a step can take is the larger of its
+operations over the peak rate (the int8 rate for the GEMMs of an int8
+recipe) and its bytes over the peak bandwidth.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
 
+from bench import cells
 from bench.peaks import Peaks
 
 VALUE_BYTES = {"none": 2, "int8": 1}
 ACT_BYTES = 2              # bf16 activations
-POSITION_BITS = {(6, 8): math.ceil(math.log2(math.comb(8, 6)))}
+DENSE = (1, 1)             # a dense weight: every 1 of 1 kept
 
 
 @dataclasses.dataclass
@@ -53,9 +58,18 @@ class Work:
                    (self.gemm_bytes + self.attn_bytes) / peaks.hbm_bytes_s)
 
 
+def pattern(c: dict) -> tuple[int, int]:
+    """(kept, group) of the configuration's weights."""
+    return tuple(c["sparsity"]["pattern"] or DENSE)
+
+
+def position_bits(z: int, l: int) -> int:
+    return math.ceil(math.log2(math.comb(l, z)))
+
+
 def linears(c: dict) -> list[tuple[int, int, int]]:
     """(input width, output width, count per step-row pass) of every
-    linear: the layers' projections, then the head."""
+    projection of a GQA attention and SwiGLU MLP layer."""
     d, f, hd = c["hidden_size"], c["intermediate_size"], c["head_dim"]
     qd, kvd = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
     n = c["num_hidden_layers"]
@@ -64,17 +78,17 @@ def linears(c: dict) -> list[tuple[int, int, int]]:
 
 
 def weight_bytes(c: dict, k_in: int, m_out: int) -> float:
-    z, l = c["sparsity"]["pattern"]
+    z, l = pattern(c)
     recipe = c["sparsity"]["recipe"]
     groups = m_out * k_in / l
-    b = groups * z * VALUE_BYTES[recipe] + groups * POSITION_BITS[(z, l)] / 8
+    b = groups * z * VALUE_BYTES[recipe] + groups * position_bits(z, l) / 8
     if recipe == "int8":
         b += 4 * m_out
     return b
 
 
 def _gemm(c: dict, k_in: int, m_out: int, rows: int) -> Work:
-    z, l = c["sparsity"]["pattern"]
+    z, l = pattern(c)
     x_bytes = 1 if c["sparsity"]["recipe"] == "int8" else ACT_BYTES
     return Work(gemm_ops=2.0 * m_out * k_in * z / l * rows,
                 gemm_bytes=weight_bytes(c, k_in, m_out)
@@ -114,14 +128,7 @@ def step_work(c: dict, lanes: list[tuple[int, int]], head_rows: int) -> Work:
     """Work of one step serving ``lanes`` (per lane: new positions, and
     context already in the cache) whose logits are taken at
     ``head_rows`` positions."""
-    rows = sum(r for r, _ in lanes)
-    w = Work()
-    for k_in, m_out, count in linears(c):
-        g = _gemm(c, k_in, m_out, rows)
-        w += Work(g.gemm_ops * count, g.gemm_bytes * count)
-    w += _gemm(c, c["hidden_size"], c["vocab_size"], head_rows)
-    w += _attention(c, lanes)
-    return w
+    return cells.family(c).step_work(c, lanes, head_rows)
 
 
 def decode_work(c: dict, contexts: list[int]) -> Work:
